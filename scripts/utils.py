@@ -8,6 +8,7 @@ introspection instead of Dask worker logs.
 from __future__ import annotations
 
 import argparse
+import sys
 
 __all__ = ["cli_parser", "human_readable_size"]
 
@@ -187,9 +188,9 @@ def enable_observability(args):
 def setup_jax(args):
     """Apply precision/platform settings before first device use.
 
-    The complex backends ("jax", "numpy"+jax checks) cannot run on TPUs
-    without complex-dtype support, and float64 is CPU-only in practice —
-    route those to the CPU platform. The planar backend runs anywhere.
+    Every backend runs on the default platform: a v5e runs complex64
+    matmuls and `jnp.fft` (probed on the chip, PR 21). float64 has no
+    TPU support, so ``--precision f64`` pins the CPU, and says so.
     """
     import jax
 
@@ -201,8 +202,9 @@ def setup_jax(args):
 
         initialize_multihost()
     if args.precision == "f64":
+        print("--precision f64: running on the CPU (no float64 on TPU)",
+              file=sys.stderr)
         jax.config.update("jax_enable_x64", True)
-    if args.backend != "planar" or args.precision == "f64":
         jax.config.update("jax_platforms", "cpu")
     return jax
 

@@ -329,18 +329,15 @@ class MetricsRegistry:
         Per stage: count, wall aggregates (total/min/mean/max/p99) and,
         where the instrumentation attributed analytic FLOPs, the derived
         ``tflops`` plus ``mfu_pct`` against the chip's peak
-        (``utils.flops.peak_tflops``; absent when no peak is known —
-        CPU, unknown device kinds without SWIFTLY_PEAK_TFLOPS).
+        (``utils.flops.peak_tflops``; absent on the CPU, and an unknown
+        accelerator raises there).
         """
         peak = None
         with self._lock:
             if any(st.flops for st in self.stages.values()):
-                try:
-                    from ..utils.flops import peak_tflops
+                from ..utils.flops import peak_tflops
 
-                    peak = peak_tflops()
-                except Exception:  # pragma: no cover - no jax devices
-                    peak = None
+                peak = peak_tflops()
             stages = {}
             tot_wall = 0.0
             tot_flops = 0

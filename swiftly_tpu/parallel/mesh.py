@@ -108,18 +108,8 @@ def varying(x, axis_name: str):
     shard_map tracks which values vary per shard; a `jnp.zeros` scan
     carry created inside the mapped body starts out unvarying and fails
     the carry-type check once the scan body mixes in shard-varying data.
-
-    The tagging primitive moved across jax releases (`pcast` since 0.6,
-    `pvary` in some 0.5.x); on older jax (0.4.x) shard_map has no
-    varying-type tracking at all, so the identity is exactly right.
     """
-    pcast = getattr(jax.lax, "pcast", None)
-    if pcast is not None:
-        return pcast(x, (axis_name,), to="varying")
-    pvary = getattr(jax.lax, "pvary", None)
-    if pvary is not None:
-        return pvary(x, (axis_name,))
-    return x
+    return jax.lax.pcast(x, (axis_name,), to="varying")
 
 
 def pad_to_shards(n: int, n_shards: int) -> int:

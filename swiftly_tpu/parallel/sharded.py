@@ -40,10 +40,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:  # jax >= 0.6 exports shard_map at top level
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
+from jax import shard_map as _shard_map
 
 import numpy as np
 
@@ -128,23 +125,18 @@ def collective_sum(x, axis_name: str, collective: str = "psum",
     return jax.lax.psum(x, axis_name)
 
 
-def _mapped(fn, mesh, in_specs, out_specs, check_rep: bool = True):
-    """shard_map with an optional check_rep=False escape hatch.
+def _mapped(fn, mesh, in_specs, out_specs, check_vma: bool = True):
+    """shard_map with an optional check_vma=False escape hatch.
 
     Ring kernels mix `ppermute`/`axis_index` results into replicated
     outputs — correct (every shard materialises the same gathered sum)
     but not provable by the replication checker, so they opt out the
     same way streamed.py's `_shmap` does. psum kernels keep the check.
     """
-    if check_rep:
-        return _shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    try:
-        return _shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False,
-        )
-    except TypeError:  # pragma: no cover - jax without check_rep kwarg
-        return _shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+    return _shard_map(
+        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=check_vma,
+    )
 
 
 def _scoped(name, fn):
@@ -196,7 +188,7 @@ def _forward_kernel(core, mesh, subgrid_size: int, collective: str = "psum"):
         mesh=mesh,
         in_specs=(P(FACET_AXIS), P(FACET_AXIS), P(FACET_AXIS), P(), P(), P()),
         out_specs=P(),
-        check_rep=collective != "ring",
+        check_vma=collective != "ring",
     )
     return jax.jit(mapped)
 
@@ -319,7 +311,7 @@ def _forward_column_kernel(core, mesh, subgrid_size: int,
             P(FACET_AXIS), P(FACET_AXIS), P(FACET_AXIS), P(), P(), P(), P(),
         ),
         out_specs=P(),
-        check_rep=collective != "ring",
+        check_vma=collective != "ring",
     )
     return jax.jit(mapped)
 
@@ -384,7 +376,7 @@ def _forward_all_kernel(core, mesh, subgrid_size: int,
             P(FACET_AXIS), P(FACET_AXIS), P(FACET_AXIS), P(), P(), P(), P(),
         ),
         out_specs=P(),
-        check_rep=collective != "ring",
+        check_vma=collective != "ring",
     )
     return jax.jit(mapped)
 

@@ -25,7 +25,7 @@ in the *edges*, and those are pinned down here:
   *stream* that truncates is unrecoverable: framing cannot resync, so
   callers drop the connection).
 
-Error taxonomy (all under `WireError`):
+Error classes (all under `WireError`):
 
 ====================  ==========================  =====================
 error                 meaning                     retry classification

@@ -35,6 +35,9 @@ frames. What the thread fleet asserted, this tier must *survive*:
   `resilience.retry.backoff_delay`-capped delays (``proc.restarts``),
   and the restarted worker re-earns trust through the breaker's
   half-open path — its trips are NOT erased by the restart.
+* **CPU-only workers.** Every worker is spawned with
+  ``JAX_PLATFORMS=cpu``: a chip belongs to one process at a time, so
+  the worker processes never share the router host's accelerator.
 * **Startup hygiene.** Fleet start sweeps run directories abandoned by
   a crashed parent: stale unix-socket files are removed
   (``proc.stale_sockets_swept``) and orphaned worker processes —
@@ -895,8 +898,11 @@ class ProcessFleet:
         w.generation += 1
         w.sock_path = os.path.join(
             self.run_dir, f"worker-{w.rid}.g{w.generation}.sock")
-        env = dict(os.environ)
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        # Workers are CPU-only: a chip belongs to one process, so N
+        # workers may not share the router host's accelerator.
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
+        log.info("procfleet worker %d (generation %d) runs CPU-only "
+                 "(JAX_PLATFORMS=cpu)", w.rid, w.generation)
         logf = open(os.path.join(
             self.run_dir, f"worker-{w.rid}.g{w.generation}.log"), "wb")
         w.proc = subprocess.Popen(

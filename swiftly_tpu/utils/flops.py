@@ -78,8 +78,8 @@ def _on_tpu() -> bool:
 
 
 # Minimum stage-2 contraction depth (facets_in_program * m) for "auto"
-# to pick the einsum FORWARD body. Measured on v5e
-# (docs/performance.md): despite ~2x the chain's matmul FLOPs, the
+# to pick the einsum FORWARD body. Measured on v5e through the round-5
+# runtime (now gone): despite ~2x the chain's matmul FLOPs, the
 # einsum body won at every measured forward shape — resident 32k
 # (K = 9*256: 14.6 -> 12.2 s) AND facet-slab 64k (K = 1*256:
 # 66.7 -> 61.7 s) — so "auto" currently resolves einsum everywhere;
@@ -387,10 +387,12 @@ _PEAKS_BF16 = {
 
 
 def peak_tflops(device=None) -> float | None:
-    """Peak f32-HIGHEST matmul TFLOP/s for the current device, or None.
+    """Peak f32-HIGHEST matmul TFLOP/s for the current device.
 
-    Override with SWIFTLY_PEAK_TFLOPS (e.g. from a measured matmul
-    roofline) when the device is not in the table.
+    None on the CPU, which has no MFU. An accelerator whose
+    ``device_kind`` is not in the table raises: an MFU against a guessed
+    peak is no number at all. SWIFTLY_PEAK_TFLOPS overrides the table
+    (e.g. with a measured matmul roofline).
     """
     env = os.environ.get("SWIFTLY_PEAK_TFLOPS")
     if env:
@@ -399,8 +401,13 @@ def peak_tflops(device=None) -> float | None:
         import jax
 
         device = jax.devices()[0]
-    kind = getattr(device, "device_kind", "")
+    if device.platform == "cpu":
+        return None
+    kind = str(device.device_kind)
     for name, bf16 in _PEAKS_BF16.items():
-        if name.lower() in str(kind).lower():
+        if name.lower() in kind.lower():
             return bf16 / 3.0  # HIGHEST = 3 bf16 MXU passes
-    return None
+    raise ValueError(
+        f"no peak TFLOP/s known for device_kind {kind!r}; add it to "
+        "utils.flops._PEAKS_BF16 or set SWIFTLY_PEAK_TFLOPS"
+    )

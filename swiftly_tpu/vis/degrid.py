@@ -95,22 +95,38 @@ def _degrid_fn(support, use_pallas):
     from jax.experimental import pallas as pl
 
     def kernel(pr_ref, pi_ref, cu_ref, cv_ref, vr_ref, vi_ref):
-        # one VMEM pass: weight outer product and both plane
-        # reductions fused per B-block (VPU work; W*W is tiny, the
-        # win is never materialising [B, W, W] weights in HBM)
-        w2 = cu_ref[:, :, None] * cv_ref[:, None, :]
-        vr_ref[...] = jnp.sum(pr_ref[...] * w2, axis=(1, 2))
-        vi_ref[...] = jnp.sum(pi_ref[...] * w2, axis=(1, 2))
+        # one VMEM pass: both separable tap reductions fused per
+        # B-block (VPU work; W*W is tiny, the win is never
+        # materialising [B, W, W] weights in HBM). Mosaic lowers a
+        # last-axis reduction to a 2-D [B, 1] output, not a reduction
+        # over two axes to a 1-D one.
+        cu = cu_ref[...]
+        cv = cv_ref[...][:, None, :]
+        vr_ref[...] = jnp.sum(
+            jnp.sum(pr_ref[...] * cv, axis=2) * cu, axis=1, keepdims=True
+        )
+        vi_ref[...] = jnp.sum(
+            jnp.sum(pi_ref[...] * cv, axis=2) * cu, axis=1, keepdims=True
+        )
 
     def body(row_r, row_i, iu0, iv0, cu, cv):
         pr = gather(row_r, iu0, iv0)
         pi = gather(row_i, iu0, iv0)
-        out = jax.ShapeDtypeStruct((pr.shape[0],), pr.dtype)
-        return pl.pallas_call(
+        B = pr.shape[0]
+        bb = min(B, 256)  # B is a power of two; [bb, W, W] pads to 128 lanes
+        p_spec = pl.BlockSpec((bb, support, support), lambda i: (i, 0, 0))
+        c_spec = pl.BlockSpec((bb, support), lambda i: (i, 0))
+        o_spec = pl.BlockSpec((bb, 1), lambda i: (i, 0))
+        out = jax.ShapeDtypeStruct((B, 1), pr.dtype)
+        vr, vi = pl.pallas_call(
             kernel,
+            grid=(B // bb,),
+            in_specs=[p_spec, p_spec, c_spec, c_spec],
+            out_specs=[o_spec, o_spec],
             out_shape=(out, out),
             interpret=pallas_interpret(),
         )(pr, pi, cu, cv)
+        return vr[:, 0], vi[:, 0]
 
     return jax.jit(body)
 

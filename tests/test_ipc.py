@@ -11,7 +11,7 @@ cross-boundary surface, pinned at its edges:
   version-mismatched frames raise their named `WireError` subclass
   immediately (never hang, never return garbage), each on a fresh
   connection because fatal framing errors cannot resync by design;
-* RETRY TAXONOMY — `WireDeadline` is a `TimeoutError` and
+* RETRY CLASSES — `WireDeadline` is a `TimeoutError` and
   `TruncatedFrame` a `ConnectionError` (both transient under the PR-4
   ladder); the four fatal errors are deterministic and NOT transient.
 
@@ -239,11 +239,11 @@ def test_encode_oversized_payload_rejected(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# retry taxonomy
+# retry classes
 # ---------------------------------------------------------------------------
 
 
-def test_error_taxonomy_and_transience():
+def test_error_classes_and_transience():
     # transient: the retry ladder may re-try these
     assert issubclass(WireDeadline, TimeoutError)
     assert issubclass(TruncatedFrame, ConnectionError)

@@ -146,8 +146,8 @@ def test_fused_multicolumn_parity(cover):
 
 
 def test_checksum_queue_backpressure_serves(cover, monkeypatch):
-    """SWIFTLY_QUEUE_CHECKSUM=1 (the tunnel-runtime pull backpressure
-    the FlightQueue documents): the service's dispatches run through
+    """SWIFTLY_QUEUE_CHECKSUM=1 (the element-pull backpressure the
+    FlightQueue documents): the service's dispatches run through
     genuine element pulls and results stay bit-identical."""
     monkeypatch.setenv("SWIFTLY_QUEUE_CHECKSUM", "1")
     config, _tasks, sgs = cover
