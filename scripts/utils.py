@@ -144,8 +144,7 @@ def cli_parser(description: str) -> argparse.ArgumentParser:
         "--metrics",
         action="store_true",
         help="enable the per-stage metrics registry (swiftly_tpu.obs): "
-             "host stage timers paired with jax.profiler "
-             "TraceAnnotations, per-stage FLOPs/MFU, and a telemetry "
+             "host stage timers, per-stage FLOPs/MFU, and a telemetry "
              "block in the summary artifact (equivalent to "
              "SWIFTLY_METRICS=1)",
     )

@@ -7,11 +7,15 @@ perf artifact this repo emits is *measured, attributed and auditable*:
 
 * ``obs.metrics`` — a near-zero-overhead metrics registry (counters,
   gauges, stage timers with min/mean/max/p99). Disabled (the default)
-  every instrumentation site costs one attribute check; enabled, each
-  stage pairs a host wall-clock timer with a
-  ``jax.profiler.TraceAnnotation`` of the SAME name, so Perfetto traces
-  and host metrics index by one stage vocabulary. Optional JSONL event
-  log + dict export with per-stage analytic FLOPs/MFU.
+  every instrumentation site costs a few attribute checks and one
+  ``TraceAnnotation.is_enabled()`` call; enabled, each stage times the
+  host wall clock. Optional JSONL event log + dict export with
+  per-stage analytic FLOPs/MFU.
+* Whenever a ``jax.profiler`` session records, every stage and every
+  ``obs.trace`` span is also a ``jax.profiler.TraceAnnotation`` of the
+  SAME name, whether or not the registry, tracer or recorder is on —
+  one gate, in ``obs.trace`` — so profiles and host metrics index by
+  one stage vocabulary on the profiler's clock.
 * ``obs.manifest`` — the run-provenance record (device kind, SWIFTLY_*
   env knobs, git SHA, config hash, ``baseline_source``) stamped into
   every BENCH artifact, plus the artifact schema validator the
@@ -19,8 +23,8 @@ perf artifact this repo emits is *measured, attributed and auditable*:
 * ``obs.trace`` — the hierarchical span tracer (run → bench leg →
   pass → column group → stage; serve request journeys on per-request
   tracks; HBM watermarks at span boundaries), exporting Chrome
-  trace-event JSON loadable in Perfetto. Same one-attribute-check
-  discipline when disabled; every ``metrics.stage`` site doubles as a
+  trace-event JSON loadable in Perfetto. Same shared-no-op discipline
+  when disabled; every ``metrics.stage`` site doubles as a
   trace site through the bridge.
 * ``obs.report`` — trace analysis: span trees, critical-path/self-time
   attribution (``scripts/trace_report.py``), journey decomposition,

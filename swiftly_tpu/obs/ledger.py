@@ -116,13 +116,26 @@ EXEMPT_STAGE_TIMERS = {
                "priced into the stage's effective rate, not separately",
     "fwd.d2h": "subgrid drain hidden behind compute by the double "
                "buffer; part of the column stage's effective rate",
-    "fwd.drain": "end-of-stream flush of in-flight buffers (bounded "
-                 "tail, not steady-state work)",
-    "fwd.facet_upload": "one-time facet-stack upload (setup, amortized "
-                        "over the whole run)",
-    "fwd.slab_prefetch": "async slab h2d the slab compute hides; the "
-                         "exposed part surfaces in fwd.slab_step",
-    "fwd.slab_upload": "synchronous slab upload fallback (setup path)",
+    "fwd.drain": "completion pull of an earlier dispatch's checksum "
+                 "(the previous group's, or slab d-2's in the slab "
+                 "stream): backpressure on work already priced",
+    "fwd.facet_prepare": "one-time host conversion of the facets to "
+                         "their host layout (setup)",
+    "fwd.facet_stack": "one-time host stack of the resident facet "
+                       "planes (setup)",
+    "fwd.facet_upload": "one-time h2d of the stacked resident facets, "
+                        "or their device synthesis (setup)",
+    "fwd.slab_prefetch": "host copy of the next slab into its staging "
+                         "buffer on the prefetch thread, hidden behind "
+                         "the dispatch loop; the exposed part is "
+                         "fwd.slab_wait",
+    "fwd.slab_wait": "the dispatch loop blocked on the prefetch "
+                     "thread's slab copy: staging the prefetch did not "
+                     "hide",
+    "fwd.slab_stage": "inline host copy of a slab into its staging "
+                      "buffer (prefetch off, or a missed prefetch)",
+    "fwd.slab_upload": "h2d dispatch of one staged facet slab; the "
+                       "transfer overlaps the previous slab's compute",
     "fwd.group_finish": "column-group boundary bookkeeping",
     "spill.read": "cache read the feed prefetch hides; the exposed "
                   "feed wall is priced as bwd.feed_group",

@@ -3,14 +3,18 @@
 Design constraints, in order:
 
 1. **Zero cost off.** The engine's hot loops call ``metrics.stage(...)``
-   per dispatch; disabled (the default) that is one attribute check and
-   the return of a shared no-op context manager — no allocation, no
-   clock read, no string work. A disabled run is indistinguishable from
-   an uninstrumented one (< 1 us per site against multi-ms dispatches).
-2. **One stage vocabulary.** Enabled, each stage timer also enters a
-   ``jax.profiler.TraceAnnotation`` of the same name, so the host-side
-   walls in ``export()`` and the device timeline in a Perfetto trace
-   (``utils.profiling.trace``) index by identical stage names.
+   per dispatch; disabled (the default) that is three attribute checks,
+   one ``TraceAnnotation.is_enabled()`` call and the return of a shared
+   no-op context manager — no allocation, no clock read, no string
+   work. A disabled run is indistinguishable from an uninstrumented one
+   (< 1 us per site against multi-ms dispatches).
+2. **One stage vocabulary.** Whenever a ``jax.profiler`` session
+   records, each stage enters a ``jax.profiler.TraceAnnotation`` of the
+   same name — with the registry, the tracer and the flight recorder on
+   or off — so the host-side walls in ``export()`` and the device
+   timeline of a profile (``utils.profiling.trace``) index by identical
+   stage names. The stage opens its span through ``obs.trace``, the one
+   place that decides whether to annotate.
 3. **Honest attribution.** JAX dispatch is asynchronous: a host timer
    around a dispatch measures dispatch + backpressure, not device
    compute. The engine therefore instruments its *completion pulls* as
@@ -57,26 +61,11 @@ __all__ = [
 _P99_RING = 8192  # per-stage sample capacity (see module docstring)
 
 
-class _NullStage:
-    """The shared disabled-path context manager (no state, no work).
-
-    Attribute writes are swallowed so call sites may set
-    ``st.flops``/``st.bytes_moved`` inside the block (for values only
-    known after the work) without branching on enablement."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-    def __setattr__(self, name, value):
-        pass
-
-
-_NULL_STAGE = _NullStage()
+# The shared disabled-path context manager is the tracer's: attribute
+# writes are swallowed, so call sites may set ``st.flops`` /
+# ``st.bytes_moved`` inside the block (for values only known after the
+# work) without branching on enablement.
+_NULL_STAGE = _trace._NULL_SPAN
 
 
 class _StageStats:
@@ -122,47 +111,37 @@ def _p99(samples):
 
 
 class _Stage:
-    """One enabled stage timing: host wall + TraceAnnotation pairing.
+    """One enabled stage timing: host wall + a span of the same name.
 
-    Also the metrics→trace bridge: when the span tracer (``obs.trace``)
-    is on, each stage opens a trace span of the SAME name, so every
-    instrumentation site in the engine feeds both systems with one
-    ``with`` block and the Perfetto timeline uses the documented stage
-    vocabulary. A stage may run with the registry disabled (tracing
-    only) — it then records no registry state."""
+    Also the metrics→trace bridge: each stage opens an ``obs.trace``
+    span of the SAME name — a traced span when the span tracer is on, a
+    bare profiler annotation while only a profiler session records, the
+    no-op otherwise — so every instrumentation site in the engine feeds
+    every system with one ``with`` block and the timelines use the
+    documented stage vocabulary. A stage may run with the registry
+    disabled (tracing only) — it then records no registry state."""
 
-    __slots__ = ("_reg", "name", "flops", "bytes_moved", "_t0", "_ann",
-                 "_tspan")
+    __slots__ = ("_reg", "name", "flops", "bytes_moved", "_t0", "_tspan")
 
     def __init__(self, reg, name, flops, bytes_moved):
         self._reg = reg
         self.name = name
         self.flops = flops
         self.bytes_moved = bytes_moved
-        self._ann = None
-        self._tspan = None
 
     def __enter__(self):
-        reg = self._reg
-        if reg._annotation_cls is not None:
-            self._ann = reg._annotation_cls(self.name)
-            self._ann.__enter__()
-        if _trace._TRACER.enabled:
-            self._tspan = _trace.span(self.name, cat="stage")
-            self._tspan.__enter__()
+        self._tspan = _trace.span(self.name, cat="stage")
+        self._tspan.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         wall = time.perf_counter() - self._t0
-        if self._tspan is not None:
-            if self.flops:
-                self._tspan.set(flops=self.flops)
-            if self.bytes_moved:
-                self._tspan.set(bytes_moved=self.bytes_moved)
-            self._tspan.__exit__(*exc)
-        if self._ann is not None:
-            self._ann.__exit__(*exc)
+        if self.flops:
+            self._tspan.set(flops=self.flops)
+        if self.bytes_moved:
+            self._tspan.set(bytes_moved=self.bytes_moved)
+        self._tspan.__exit__(*exc)
         if self._reg.enabled:
             self._reg._record_stage(self.name, wall, self.flops,
                                     self.bytes_moved)
@@ -181,7 +160,6 @@ class MetricsRegistry:
 
     def __init__(self, enabled=False, jsonl_path=None):
         self._lock = threading.Lock()
-        self._annotation_cls = None
         self._jsonl = None
         self._jsonl_path = None
         self._t_epoch = time.time()
@@ -197,23 +175,11 @@ class MetricsRegistry:
     # -- lifecycle ---------------------------------------------------------
 
     def enable(self, jsonl_path=None):
-        """Turn recording on; optionally start a JSONL event log.
-
-        The TraceAnnotation class is resolved here (not per stage) so
-        enabled-path overhead stays one attribute read; environments
-        without ``jax.profiler`` degrade to host timers only.
-        """
+        """Turn recording on; optionally start a JSONL event log."""
         with self._lock:
             self.enabled = True
             self._t_epoch = time.time()
             self._t0 = time.perf_counter()
-            if self._annotation_cls is None:
-                try:
-                    from jax.profiler import TraceAnnotation
-
-                    self._annotation_cls = TraceAnnotation
-                except Exception:  # pragma: no cover - no jax.profiler
-                    self._annotation_cls = None
             if jsonl_path:
                 self._jsonl_path = str(jsonl_path)
                 self._jsonl = open(self._jsonl_path, "a", buffering=1)
@@ -248,14 +214,15 @@ class MetricsRegistry:
         and data-movement attribution (accumulated into the stage).
         Disabled this returns a shared no-op object immediately —
         unless the span tracer is on (the stage runs as a trace-only
-        span, no registry state) or the flight recorder is on (a
-        recorder-only timer appends one ring event).
+        span, no registry state), the flight recorder is on (a
+        recorder-only timer appends one ring event) or a profiler
+        session records (a bare profiler annotation of the name).
         """
-        if not self.enabled and not _trace._TRACER.enabled:
-            if _recorder._RECORDER.enabled:
-                return _recorder._RecorderStage(name)
-            return _NULL_STAGE
-        return _Stage(self, name, flops, bytes_moved)
+        if self.enabled or _trace._TRACER.enabled:
+            return _Stage(self, name, flops, bytes_moved)
+        if _recorder._RECORDER.enabled:
+            return _recorder._RecorderStage(name)
+        return _trace.span(name, cat="stage")
 
     def observe(self, name, wall_s, flops=0, bytes_moved=0):
         """Record an externally measured duration into a stage histogram.
@@ -429,12 +396,7 @@ def reset():
 
 
 def stage(name, flops=0, bytes_moved=0):
-    # keep the disabled path shallow: three attribute checks, shared no-op
-    if not _REGISTRY.enabled and not _trace._TRACER.enabled:
-        if _recorder._RECORDER.enabled:
-            return _recorder._RecorderStage(name)
-        return _NULL_STAGE
-    return _Stage(_REGISTRY, name, flops, bytes_moved)
+    return _REGISTRY.stage(name, flops, bytes_moved)
 
 
 def observe(name, wall_s, flops=0, bytes_moved=0):

@@ -40,6 +40,8 @@ import json
 import os
 import time
 
+from . import trace as _trace
+
 __all__ = [
     "FlightRecorder",
     "disable",
@@ -177,9 +179,11 @@ class _RecorderStage:
     """The recorder-only stage timer: what ``metrics.stage`` returns
     when the registry and tracer are both off but the recorder is on.
     One clock read each side of the block plus one ring append — the
-    <5 us/event contract tests/test_trace.py asserts."""
+    <5 us/event contract tests/test_trace.py asserts — and, while a
+    profiler session records, an annotation of the name (``obs.trace``
+    decides)."""
 
-    __slots__ = ("name", "flops", "bytes_moved", "_t0")
+    __slots__ = ("name", "flops", "bytes_moved", "_t0", "_span")
 
     def __init__(self, name):
         self.name = name
@@ -187,11 +191,14 @@ class _RecorderStage:
         self.bytes_moved = 0
 
     def __enter__(self):
+        self._span = _trace.span(self.name, cat="stage")
+        self._span.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()
+        self._span.__exit__(*exc)
         _RECORDER.record("stage", self.name, round(t1 - self._t0, 6))
         return False
 

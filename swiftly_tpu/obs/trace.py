@@ -11,16 +11,21 @@ to the Chrome trace-event JSON format any Perfetto UI loads.
 
 Design constraints, in the ``metrics.py`` discipline:
 
-1. **Zero cost off.** Disabled (the default), ``trace.span(...)`` is
-   one attribute check and the return of a shared no-op context
-   manager — no allocation, no clock read, no contextvar touch. Every
-   ``metrics.stage(...)`` site doubles as a trace site through the
-   bridge in ``metrics._Stage``, so the engine's hot loops carry ONE
-   set of instrumentation for both systems.
-2. **One vocabulary.** Spans opened by the metrics bridge carry the
-   stage names documented in docs/observability.md, so host spans line
-   up with the ``jax.profiler.TraceAnnotation`` device tracks when
-   both traces are loaded side by side.
+1. **Zero cost off.** Disabled (the default) and with no profiler
+   session recording, ``trace.span(...)`` is one attribute check, one
+   ``TraceAnnotation.is_enabled()`` call and the return of a shared
+   no-op context manager — no allocation, no clock read, no contextvar
+   touch. Every ``metrics.stage(...)`` site doubles as a trace site
+   (``metrics.stage`` opens its spans here), so the engine's hot loops
+   carry ONE set of instrumentation for both systems.
+2. **One gate onto the profiler's clock.** Whenever a
+   ``jax.profiler`` session records, every span — and so every stage —
+   also enters a ``jax.profiler.TraceAnnotation`` of its name (its
+   args as metadata), whatever the state of the tracer, the metrics
+   registry and the flight recorder. The profile's host lines then
+   hold the program's own stage vocabulary on the same clock as the
+   device planes. This module is the one place that decides it, so no
+   stage is annotated twice.
 3. **Hierarchy via contextvars.** The current span is a context
    variable: nested ``with`` blocks build the run → bench leg → pass →
    column group → stage tree automatically, async-task-safe. Worker
@@ -56,6 +61,8 @@ import os
 import threading
 import time
 
+from jax.profiler import TraceAnnotation as _TraceAnnotation
+
 __all__ = [
     "Tracer",
     "adopt",
@@ -83,10 +90,12 @@ _CURRENT = contextvars.ContextVar("swiftly_trace_span", default=0)
 
 
 class _NullSpan:
-    """The shared disabled-path context manager (no state, no work).
+    """The shared disabled-path context manager (no state, no work) —
+    also what a disabled ``metrics.stage`` returns.
 
     Attribute writes and ``set(...)`` calls are swallowed so call sites
-    may annotate spans unconditionally without branching on enablement.
+    may annotate spans (``st.bytes_moved = ...``) unconditionally without
+    branching on enablement.
     """
 
     __slots__ = ()
@@ -107,11 +116,40 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
+class _ProfilerSpan(_NullSpan):
+    """A span only a recording profiler session keeps: a
+    ``TraceAnnotation`` of the span's name on the profiler's clock, and
+    no tracer state. Attribute writes and ``set(...)`` are swallowed as
+    on the no-op."""
+
+    __slots__ = ("_ann",)
+
+    def __init__(self, name, args):
+        object.__setattr__(self, "_ann", _TraceAnnotation(name, **args))
+
+    def __enter__(self):
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._ann.__exit__(*exc)
+        return False
+
+
+def _untraced(name, args):
+    """What a span site gets with the tracer off: the shared no-op, or
+    a profiler annotation while a session records."""
+    if _TraceAnnotation.is_enabled():
+        return _ProfilerSpan(name, args)
+    return _NULL_SPAN
+
+
 class _Span:
-    """One enabled span: perf_counter bracket + contextvar parenting."""
+    """One enabled span: perf_counter bracket + contextvar parenting
+    (and a profiler annotation of its name while a session records)."""
 
     __slots__ = ("_tr", "id", "parent", "name", "cat", "args", "tid",
-                 "_t0", "_token")
+                 "_t0", "_token", "_ann")
 
     def __init__(self, tracer, name, cat, args):
         self._tr = tracer
@@ -125,6 +163,8 @@ class _Span:
         return self
 
     def __enter__(self):
+        self._ann = _untraced(self.name, self.args)
+        self._ann.__enter__()
         self.id = next(_SPAN_IDS)
         self.parent = _CURRENT.get()
         self._token = _CURRENT.set(self.id)
@@ -139,6 +179,7 @@ class _Span:
         except ValueError:  # pragma: no cover - exited in a peer context
             _CURRENT.set(self.parent)
         self._tr._finish(self, self._t0, t1)
+        self._ann.__exit__(*exc)
         return False
 
 
@@ -215,9 +256,10 @@ class Tracer:
 
     def span(self, name, cat="host", **args):
         """Context manager opening one span as a child of the current
-        context; disabled this returns the shared no-op immediately."""
+        context; disabled this returns the shared no-op immediately, or
+        a bare profiler annotation while a profiler session records."""
         if not self.enabled:
-            return _NULL_SPAN
+            return _untraced(name, args)
         return _Span(self, name, cat, args)
 
     def name_track(self, tid, label):
@@ -479,8 +521,8 @@ def reset():
 
 
 def span(name, cat="host", **args):
-    if not _TRACER.enabled:  # keep the disabled path one check deep
-        return _NULL_SPAN
+    if not _TRACER.enabled:  # keep the disabled path two checks deep
+        return _untraced(name, args)
     return _Span(_TRACER, name, cat, args)
 
 

@@ -133,6 +133,7 @@ def cmatmul_pallas(zr, zi, wr, wi, *, bm=256, bn=256, bk=256,
         out_specs=[o_spec, o_spec],
         out_shape=[out_shape, out_shape],
         interpret=interpret,
+        name="cmatmul_pallas",
     )(zr_p, zi_p, wr_p, wi_p)
     return outr[:B, :N], outi[:B, :N]
 
@@ -215,6 +216,7 @@ def bwd_fold_pallas(acc_r, acc_i, bc, bs, rr, ri, w, *, bm=256, bn=256,
         out_specs=[a_spec, a_spec],
         out_shape=[out_shape, out_shape],
         interpret=interpret,
+        name="bwd_fold_pallas",
     )(ar_p, ai_p, bc_p, bs_p, rr_p, ri_p, w_p)
     return outr[:B, :J], outi[:B, :J]
 
@@ -337,5 +339,6 @@ def colpass_pallas(ar, ai, xr, xi, br, bi, *, reduce_f=True, bm=256,
             vmem_limit_bytes=_COLPASS_VMEM_LIMIT
         ),
         interpret=interpret,
+        name="colpass_pallas",
     )(ar_p, ai_p, xr_p, xi_p, br_p, bi_p)
     return outr[..., :M, :N], outi[..., :M, :N]

@@ -119,10 +119,13 @@ def ring_allreduce(x, axis_name: str, n_shards: int | None = None):
 def collective_sum(x, axis_name: str, collective: str = "psum",
                    n_shards: int | None = None):
     """The facet-axis reduction under the selected schedule: blocking
-    `lax.psum` (XLA all-reduce) or the `ppermute` ring."""
+    `lax.psum` (XLA all-reduce, under its own ``swiftly/mesh.psum``
+    scope: the host stage timing the same wait has that name) or the
+    `ppermute` ring."""
     if collective == "ring":
         return ring_allreduce(x, axis_name, n_shards)
-    return jax.lax.psum(x, axis_name)
+    with jax.named_scope("swiftly/mesh.psum"):
+        return jax.lax.psum(x, axis_name)
 
 
 def _mapped(fn, mesh, in_specs, out_specs, check_vma: bool = True):

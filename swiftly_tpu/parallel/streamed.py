@@ -149,8 +149,9 @@ from ..resilience.retry import retry_transient as _retry  # noqa: E402
 def _scoped(name, fn):
     """Wrap a stage body in ``jax.named_scope`` so its compiled HLO ops
     carry the stage name — the trace-side half of the shared stage
-    vocabulary (the host-side half is ``obs.metrics``' TraceAnnotation
-    of the same name minus the "swiftly/" prefix). Zero runtime cost:
+    vocabulary (the host-side half is the TraceAnnotation each
+    ``obs.metrics`` stage enters while a profiler session records, of
+    the same name minus the "swiftly/" prefix). Zero runtime cost:
     the scope exists only at trace time, as op-name metadata."""
 
     def wrapped(*args, **kwargs):
@@ -2555,42 +2556,44 @@ class StreamedForward:
             and self._base.residency == "device"
             and self._base.mesh is None
         )
-        for _, d in facet_tasks:
-            raw = d() if callable(d) else d
-            if isinstance(raw, SparseRealFacet):
-                # keep sparse where the device-synthesis paths can use
-                # it (planar single-device sampled executors); densify
-                # for everything else
-                if sparse_ok:
-                    store.append(raw)
+        # set-up: every facet converted to its host layout
+        with _metrics.stage("fwd.facet_prepare"):
+            for _, d in facet_tasks:
+                raw = d() if callable(d) else d
+                if isinstance(raw, SparseRealFacet):
+                    # keep sparse where the device-synthesis paths can use
+                    # it (planar single-device sampled executors); densify
+                    # for everything else
+                    if sparse_ok:
+                        store.append(raw)
+                        real_flags.append(True)
+                        sparse_flags.append(True)
+                        continue
+                    raw = raw.densify(_np_dtype(core))
+                plane = _real_plane_or_none(core, raw)
+                if plane is not None:
+                    store.append(plane)
                     real_flags.append(True)
-                    sparse_flags.append(True)
-                    continue
-                raw = raw.densify(_np_dtype(core))
-            plane = _real_plane_or_none(core, raw)
-            if plane is not None:
-                store.append(plane)
-                real_flags.append(True)
-            else:
-                store.append(_to_host_layout(core, raw))
-                real_flags.append(False)
-            sparse_flags.append(False)
-            del raw
-        # all-or-nothing: mixed sparse/dense stacks densify the sparse
-        # entries (the synthesis programs scatter the WHOLE slab/stack)
-        self._facets_sparse = bool(sparse_flags) and all(sparse_flags)
-        if not self._facets_sparse and any(sparse_flags):
-            for i, (s, is_sp) in enumerate(zip(store, sparse_flags)):
-                if is_sp:
-                    store[i] = s.densify(_np_dtype(core))
-        self._facets_real = all(real_flags)
-        if not self._facets_real and any(real_flags):
-            # mixed: re-expand the real planes to planar pairs
-            for i, (s, is_real) in enumerate(zip(store, real_flags)):
-                if is_real:
-                    pair = np.zeros(s.shape + (2,), dtype=s.dtype)
-                    pair[..., 0] = s
-                    store[i] = pair
+                else:
+                    store.append(_to_host_layout(core, raw))
+                    real_flags.append(False)
+                sparse_flags.append(False)
+                del raw
+            # all-or-nothing: mixed sparse/dense stacks densify the sparse
+            # entries (the synthesis programs scatter the WHOLE slab/stack)
+            self._facets_sparse = bool(sparse_flags) and all(sparse_flags)
+            if not self._facets_sparse and any(sparse_flags):
+                for i, (s, is_sp) in enumerate(zip(store, sparse_flags)):
+                    if is_sp:
+                        store[i] = s.densify(_np_dtype(core))
+            self._facets_real = all(real_flags)
+            if not self._facets_real and any(real_flags):
+                # mixed: re-expand the real planes to planar pairs
+                for i, (s, is_real) in enumerate(zip(store, real_flags)):
+                    if is_real:
+                        pair = np.zeros(s.shape + (2,), dtype=s.dtype)
+                        pair[..., 0] = s
+                        store[i] = pair
         self._facet_data = store
         self._sparse_pad = None  # fixed per-facet pixel pad (one compile)
         self.col_group = col_group
@@ -3038,56 +3041,43 @@ class StreamedForward:
 
     def _upload_resident_facets(self):
         """Upload (or device-synthesise) the resident facet stack for the
-        sampled path — the one-time h2d cost of residency='device',
-        recorded as the `fwd.facet_upload` stage."""
+        sampled path — the one-time cost of residency='device', recorded
+        as the `fwd.facet_stack` (host stack of each plane) and
+        `fwd.facet_upload` (its h2d, or the device synthesis) stages."""
         base = self._base
         core = base.core
         yB = base.stack.size
         n_pad = base.stack.n_total - base.stack.n_real
-        with _metrics.stage("fwd.facet_upload") as st:
-            if self._facets_sparse:
+        if self._facets_sparse:
+            with _metrics.stage("fwd.facet_upload") as st:
                 # synthesise the resident stack on device: kilobytes of
                 # coordinates uploaded instead of the multi-GB planes
                 fn = _synth_slab_j(core, base.stack.n_total, yB)
                 self._dev_facets = (
                     fn(*self._sparse_pixels(0, base.stack.n_total)),
                 )
-            elif self._facets_real:
+                st.bytes_moved = int(self._dev_facets[0].nbytes)
+            return
+        if self._facets_real:
+            parts = [self._facet_data]
+        elif _planar(core):
+            # upload re/im planes as separate contiguous arrays (the
+            # sampled program must not slice them out of a stacked
+            # array — that would copy the multi-GiB stack)
+            parts = [[d[..., p] for d in self._facet_data] for p in (0, 1)]
+        else:
+            parts = [[np.asarray(d) for d in self._facet_data]]
+        planes = []
+        for part in parts:  # one plane on the host at a time
+            with _metrics.stage("fwd.facet_stack"):
                 host = np.ascontiguousarray(
-                    np.stack(
-                        self._facet_data
-                        + [np.zeros_like(self._facet_data[0])] * n_pad
-                    )
+                    np.stack(part + [np.zeros_like(part[0])] * n_pad)
                 )
-                self._dev_facets = (base._place(host),)
-            elif _planar(core):
-                # upload re/im planes as separate contiguous arrays (the
-                # sampled program must not slice them out of a stacked
-                # array — that would copy the multi-GiB stack)
-                planes = []
-                for p in (0, 1):
-                    host = np.ascontiguousarray(
-                        np.stack(
-                            [d[..., p] for d in self._facet_data]
-                            + [np.zeros_like(self._facet_data[0][..., p])]
-                            * n_pad
-                        )
-                    )
-                    planes.append(base._place(host))
-                self._dev_facets = tuple(planes)
-            else:
-                self._dev_facets = (
-                    base._place(
-                        np.stack(
-                            [np.asarray(d) for d in self._facet_data]
-                            + [np.zeros_like(np.asarray(self._facet_data[0]))]
-                            * n_pad
-                        )
-                    ),
-                )
-            st.bytes_moved = sum(
-                int(getattr(a, "nbytes", 0)) for a in self._dev_facets
-            )
+            with _metrics.stage("fwd.facet_upload") as st:
+                planes.append(base._place(host))
+                st.bytes_moved = int(host.nbytes)
+            del host
+        self._dev_facets = tuple(planes)
 
     def _device_columns(self, groups, subgrid_size, whole_groups=False):
         """Facets-resident sampled-DFT pass in column groups.
@@ -3553,34 +3543,29 @@ class StreamedForward:
                             )
                     else:
                         d = n_slab_dispatch
-                        with _metrics.stage("fwd.slab_upload") as st:
-                            bufs = None
-                            if (
-                                prefetch_fut is not None
-                                and prefetch_fut[0] == d
-                            ):
-                                # bounded wait: a wedged fill thread must
-                                # degrade to a counted miss (inline fill of
-                                # the same slot with the same bytes), never
-                                # stall the stream — host_slab is a pure
-                                # memcpy, so 120 s is ~2 orders above any
-                                # real slab
-                                try:
+                        bufs = None
+                        if prefetch_fut is not None and prefetch_fut[0] == d:
+                            # bounded wait: a wedged fill thread must
+                            # degrade to a counted miss (inline fill of
+                            # the same slot with the same bytes), never
+                            # stall the stream — host_slab is a pure
+                            # memcpy, so 120 s is ~2 orders above any
+                            # real slab
+                            try:
+                                with _metrics.stage("fwd.slab_wait"):
                                     bufs = prefetch_fut[1].result(
                                         timeout=120.0
                                     )
-                                    _metrics.count(
-                                        "fwd.slab_prefetch_hits"
-                                    )
-                                except concurrent.futures.TimeoutError:
-                                    prefetch_fut[1].cancel()
-                                prefetch_fut = None
-                            if bufs is None:
-                                if use_prefetch:
-                                    _metrics.count(
-                                        "fwd.slab_prefetch_misses"
-                                    )
+                                _metrics.count("fwd.slab_prefetch_hits")
+                            except concurrent.futures.TimeoutError:
+                                prefetch_fut[1].cancel()
+                            prefetch_fut = None
+                        if bufs is None:
+                            if use_prefetch:
+                                _metrics.count("fwd.slab_prefetch_misses")
+                            with _metrics.stage("fwd.slab_stage"):
                                 bufs = host_slab(s0, d % n_stage)
+                        with _metrics.stage("fwd.slab_upload") as st:
                             slab_dev = tuple(
                                 base._place(a) for a in bufs
                             )

@@ -9,6 +9,8 @@ Pins the tentpole contracts of the tracing layer:
   across threads via ``current()``/``adopt()`` (the serve worker pump);
 * the metrics→trace bridge: every ``metrics.stage`` site doubles as a
   trace span of the SAME name, with the registry off or on;
+* the profiler gate: while a ``jax.profiler`` session records, every
+  stage and span is one annotation of its name in the profile;
 * serve request journeys: queue/compute/transfer segments SUM to the
   measured end-to-end latency and land on per-request trace tracks;
 * Chrome export structure (Perfetto-loadable), critical-path/self-time
@@ -108,20 +110,28 @@ def test_recorder_hot_path_under_5us(global_obs_off):
     # recorder-only stage bridge stay under 5 us/event — cheap enough
     # to leave on for every drill and production serve run
     recorder.enable(seconds=60.0)
-    n = 100_000
-    t0 = time.perf_counter()
-    for _ in range(n):
-        recorder.record("stage", "fwd.column_pass", 0.001)
-    per_event = (time.perf_counter() - t0) / n
-    assert per_event < 5e-6
 
-    t0 = time.perf_counter()
-    for _ in range(n):
+    def best_per_event(body, batches=20, n=10_000):
+        # the best of several short batches: a batch that a loaded
+        # machine preempted does not decide the per-event cost
+        best = float("inf")
+        for _ in range(batches):
+            t0 = time.perf_counter()
+            for _ in range(n):
+                body()
+            best = min(best, (time.perf_counter() - t0) / n)
+        return best
+
+    def record():
+        recorder.record("stage", "fwd.column_pass", 0.001)
+
+    def stage():
         with metrics.stage("fwd.column_pass"):
             pass
-    per_event = (time.perf_counter() - t0) / n
-    assert per_event < 5e-6
-    # the ring is bounded: 200k events through a default ring stay
+
+    assert best_per_event(record) < 5e-6
+    assert best_per_event(stage) < 5e-6
+    # the ring is bounded: 400k events through a default ring stay
     # capped at capacity, newest retained
     assert len(recorder.get_recorder()._ring) <= recorder.get_recorder().capacity
 
@@ -218,6 +228,72 @@ def test_stage_sites_feed_both_when_both_enabled(global_obs_off):
     assert "bwd.sampled_fold" in metrics.export()["stages"]
     spans = report.build_tree(trace.export())
     assert {s["name"] for s in spans.values()} == {"bwd.sampled_fold"}
+
+
+def _profile_host_events(directory):
+    """``[(name, stats)]`` of the host events of the profile written
+    under ``directory``."""
+    import jax
+
+    (path,) = Path(directory).rglob("*.xplane.pb")
+    return [
+        (ev.name, dict(ev.stats))
+        for plane in jax.profiler.ProfileData.from_file(str(path)).planes
+        if plane.name.startswith("/host:CPU")
+        for line in plane.lines
+        for ev in line.events
+    ]
+
+
+def test_stages_and_spans_reach_a_recording_profile(global_obs_off,
+                                                     tmp_path):
+    """One gate: while a profiler session records, every stage and span
+    is a profiler annotation of its name — with registry, tracer and
+    recorder off, or any one of them on — and never two."""
+    import jax
+
+    recording = jax.profiler.TraceAnnotation.is_enabled
+    assert not recording()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        assert recording()
+        with metrics.stage("fwd.slab_upload", flops=3) as st:
+            st.bytes_moved = 42  # the writes call sites make are taken
+            st.flops = 7
+        with trace.span("fwd.column_group", cat="fwd", group=3) as sp:
+            sp.set(n_cols=2)
+        recorder.enable(seconds=60.0)
+        with metrics.stage("fwd.slab_wait"):
+            pass
+        recorder.disable()
+        trace.enable()
+        with metrics.stage("fwd.drain"):
+            pass
+        trace.disable()
+        metrics.enable()
+        with metrics.stage("fwd.slab_stage"):
+            pass
+        metrics.disable()
+    finally:
+        jax.profiler.stop_trace()
+    events = _profile_host_events(tmp_path)
+    names = [name for name, _ in events]
+    for name in ("fwd.slab_upload", "fwd.column_group", "fwd.slab_wait",
+                 "fwd.drain", "fwd.slab_stage"):
+        assert names.count(name) == 1, name
+    assert dict(events)["fwd.column_group"] == {"group": 3}
+    # each system still kept its own record of its stage
+    assert [e["name"] for e in recorder.get_recorder().events()] == [
+        "fwd.slab_wait"]
+    assert [s["name"] for s in trace.export()["traceEvents"]] == [
+        "fwd.drain"]
+    assert list(metrics.export()["stages"]) == ["fwd.slab_stage"]
+    # the session is closed: the shared no-ops again, and no annotation
+    # class left on the registry
+    assert not recording()
+    assert metrics.stage("fwd.slab_upload") is _NULL_STAGE
+    assert trace.span("fwd.column_group", group=3) is _NULL_SPAN
+    assert not hasattr(metrics.get_registry(), "_annotation_cls")
 
 
 def test_hbm_gauge_fallback_stamps_spans(global_trace):
