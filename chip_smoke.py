@@ -125,13 +125,19 @@ def _bodies(core, n_facets, meshed=False):
     from swiftly_tpu.parallel.streamed import (
         resolve_fold_kernel,
         resolve_fold_mode,
+        select_fold_body,
     )
     from swiftly_tpu.utils.flops import resolve_colpass, resolve_colpass_bwd
 
+    mode = resolve_fold_mode()
     return {
         "colpass_fwd": resolve_colpass(core, n_facets),
         "colpass_bwd": resolve_colpass_bwd(core, n_facets),
-        "fold": resolve_fold_mode(),
+        # the fold body of a call of 1 and of 2 columns
+        "fold": [
+            select_fold_body(mode, core.yN_size, g * core.xM_yN_size, meshed)
+            for g in (1, 2)
+        ],
         "fold_kernel": resolve_fold_kernel(core, meshed=meshed),
     }
 

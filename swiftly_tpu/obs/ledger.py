@@ -142,8 +142,6 @@ EXEMPT_STAGE_TIMERS = {
     "spill.h2d": "cache h2d dispatch inside the feed's overlap window; "
                  "priced as bwd.feed_group traffic",
     "bwd.drain": "backward end-of-stream flush (bounded tail)",
-    "bwd.ct_fold": "sub-stage of the priced backward column pass; "
-                   "mapping it too would double-count the wall",
     "bwd.fft_fold": "sub-stage of the priced adjoint fold (fft "
                     "residency variant); same double-count hazard",
     "bwd.finish": "final per-facet finish, paid once per pass outside "

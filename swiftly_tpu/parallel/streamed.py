@@ -1797,35 +1797,43 @@ def _bwd_fft_fold_chunk_sharded(core, mesh, Cj):
 
 # -- Cooley-Tukey sampled backward fold --------------------------------------
 #
-# The sampled fold evaluates out[f, i, j] = sum_r rows2[f, r, j] *
-# W^{-kt_r * i} (W = e^{+2pi i/yN}) as one dense [i, r] DFT per group —
-# 8 * R_g * yB^2 * F FLOPs, ~flat per COLUMN. Factoring the kernel the
+# The sampled fold evaluates out[f, i, j] = fb[i] * sum_r rows[f, r, j] *
+# W^{-kt_r * (e0_f + i)} (W = e^{+2pi i/yN}) as one dense [i, r] DFT per
+# call: 8 * R * yB^2 * F FLOPs, flat per COLUMN. Factoring the kernel the
 # Cooley-Tukey way over kt_r = Q*a_r + b_r and i = q*P + p (P = yN/Q):
 #
-#   W^{-kt i} = e^{-2pi i a p / P} * e^{-2pi i b p / yN} * e^{-2pi i b q / Q}
+#   W^{-kt (e0 + i)} = W^{-kt (e0 + p)} * e^{-2pi i b q / Q}
 #
-# turns the fold into three DENSE stages with no scatters or rolls:
-#   1. group rows by b-lane (a constant gather; a column's m consecutive
-#      kt values hit each b exactly ceil(m/Q) times) and contract the
-#      per-lane a-phases:        G[f,b,p,j]  (K = g*ceil(m/Q))
-#   2. elementwise twiddle e^{-2pi i b p / yN}
-#   3. one [q, b] DFT matmul:    out[f,q,p,j] -> reshape i = q*P + p
-#      (K = Q = 128, flat in group size g)
-# Stage 3 dominates at ~8 * Q * yB * yB * F FLOPs per group — R_g/Q times
-# fewer than the direct fold (3-6x at production group sizes), and the
-# MXU shapes are deep. Exactness: pure index algebra, no approximation;
+# (kt * q * P = a*q*yN + b*q*P), the fold becomes two dense stages with
+# no scatters or rolls:
+#   1. group rows by b-lane (a gather: a column's m consecutive kt values
+#      hit each b-lane ceil(m/Q) times at most) and sum each lane's rows
+#      times their phases W^{-kt (e0 + p)}: G[f,b,p,j], a multiply-add
+#      over the g*ceil(m/Q) rows of a lane, on the vector units
+#   2. one [q, b] DFT matmul: out[f,q,p,j] -> i = q*P + p
+#      (K = 2Q = 256 in planar form, flat in the number of rows)
+# Stage 2 costs 8 * Qi * Q * P * yB * F FLOPs a call (Qi = yB/P rows of
+# q) — R/Q times fewer than the dense fold. Exact: pure index algebra,
 # pinned against the sampled fold by tests at every backend.
 
+# `select_fold_body` takes the CT body for calls of at least this many
+# rows per lane (R >= CT_MIN_LANE_DEPTH * Q): the shallowest depth timed
+# on the chip, where CT won (TPU v5e, 32k, R = 2Q: 130 ms against the
+# sampled body's 260 ms a call)
+CT_MIN_LANE_DEPTH = 2
 
+
+@functools.lru_cache(maxsize=256)
 def _ct_fold_tables(core, col_offs0):
-    """Host-side index tables for the CT fold of one column group.
+    """Index table of the CT fold of one column group (``col_offs0`` a
+    tuple of column offsets).
 
-    Returns (Q, P, kmax, r_idx, a_vals): `r_idx[c, b, k]` is the global
-    row index (into R = g*m concatenated rows) of the k-th row of column
-    c landing in b-lane b (0 for pads), `a_vals[c, b, k]` its a-value in
-    [0, P) (or -1 for pads — the device masks those contributions).
-    Exact int64 host arithmetic (the in-trace version of this indexing is
-    what the int32-overflow class preys on).
+    Returns (Q, P, kmax, tab): ``tab`` is int32 [R + g*Q*kmax], the
+    group's spectral row indices kt (`sampled_row_indices`) followed by
+    `r_idx[c, b, k]`, the row (into the R = g*m concatenated rows) of
+    the k-th row of column c that lands in b-lane b, or -1 where the
+    lane has fewer rows. One array, so a call puts one table on the
+    device, as the sampled body puts its kt.
     """
     import math
 
@@ -1833,188 +1841,174 @@ def _ct_fold_tables(core, col_offs0):
     m = core.xM_yN_size
     Q = math.gcd(128, yN)
     P = yN // Q
-    kmax = -(-m // Q) if m >= Q else 1
+    kmax = -(-m // Q)
     g = len(col_offs0)
-    kt = sampled_row_indices(core, col_offs0).astype(np.int64)  # [g*m]
-    r_idx = np.zeros((g, Q, kmax), dtype=np.int32)
-    a_vals = np.full((g, Q, kmax), -1, dtype=np.int32)
-    fill = np.zeros((g, Q), dtype=np.int32)
+    kt = sampled_row_indices(core, col_offs0)
+    lane = (kt.astype(np.int64) % Q).reshape(g, m)
+    r_idx = np.full((g, Q, kmax), -1, dtype=np.int32)
     for c in range(g):
-        for rp in range(m):
-            r = c * m + rp
-            b = int(kt[r] % Q)
-            a = int((kt[r] // Q) % P)
-            k = fill[c, b]
-            r_idx[c, b, k] = r
-            a_vals[c, b, k] = a
-            fill[c, b] += 1
-    return Q, P, kmax, r_idx, a_vals
-
-
-def _ct_fold_width(yB, all_planes_bytes) -> int:
-    """Static j-width of one CT fold launch: the largest divisor of yB
-    keeping ALL facets' concurrently-scheduled stage planes near
-    SWIFTLY_CT_FOLD_MB (default 4096 MB). The TPU AOT compiler schedules
-    every unrolled block concurrently (optimization_barrier is stripped;
-    scan carries lose aliasing), so per-launch footprint is controlled
-    by width alone."""
-    import os
-
-    target = float(os.environ.get("SWIFTLY_CT_FOLD_MB", "4096")) * 1e6
-    want = max(1, int(np.ceil(all_planes_bytes / target)))
-    for n in range(want, yB + 1):
-        if yB % n == 0:
-            return yB // n
-    return 1
+        order = np.argsort(lane[c], kind="stable")
+        lanes = lane[c][order]
+        rank = np.arange(m) - np.searchsorted(lanes, lanes)
+        r_idx[c, lanes, rank] = c * m + order
+    return Q, P, kmax, np.concatenate([kt, r_idx.ravel()])
 
 
 @functools.lru_cache(maxsize=None)
-def _bwd_ct_fold_fn(core, Q, P, kmax, W, axis_name=None):
+def _ct_fold_width(yB, per_col_bytes, budget) -> int:
+    """Static j-width W of one CT fold launch: the widest divisor of yB
+    whose stage planes (``per_col_bytes`` per output column, all facets:
+    `_ct_column_bytes`) fit ``budget`` — the HBM the plan reserves for
+    the fold's transients (`plan.model.DEFAULT_RESERVE_BYTES`, which the
+    sampled fold's row blocks also live in). Lane-aligned (a multiple of
+    128) where yB has such a divisor, so each window is whole tiles of
+    the accumulator. At 32k (9 facets, 2.0 MB a column) the 1.2 GB
+    reserve gives W 512: 22 launches a call, 1.02 GB of transients
+    (compiled for v5e). On a v5e the call took 131 ms at W 512, 132 ms
+    at W 256 and 136 ms at W 1024 (g = 2): the width sets the memory,
+    not the time."""
+    cap = max(1, min(yB, int(budget // max(1, per_col_bytes))))
+    fits = [w for w in range(1, cap + 1) if yB % w == 0]
+    aligned = [w for w in fits if w % 128 == 0]
+    return max(aligned or fits)
+
+
+def _ct_column_bytes(core, n_facets, yB):
+    """Bytes of the CT fold's live stage planes per output column of a
+    launch: the lane sums and the stage-2 output, re and im, of every
+    facet."""
+    import math
+
+    Q = math.gcd(128, core.yN_size)
+    P = core.yN_size // Q
+    planes = 2 * (Q + -(-yB // P)) * P * n_facets
+    itemsize = np.dtype(core.dtype).itemsize
+    return planes * (itemsize if _planar(core) else itemsize // 2)
+
+
+def _layout(x, major_to_minor):
+    from jax.experimental.layout import Layout, with_layout_constraint
+
+    return with_layout_constraint(x, Layout(major_to_minor))
+
+
+@functools.lru_cache(maxsize=None)
+def _bwd_ct_fold_fn(core, Q, P, kmax, W):
     """acc [F, yB, yB(,2)] += one j-window [j0, j0+W) of the CT-factored
     adjoint-sampled fold of concatenated column rows [F, R, yB(,2)]
-    (same input layout and accumulator contract as
-    `_bwd_sampled_fold_fn`); the caller loops yB/W windows, donating the
+    (the accumulator contract of `_bwd_sampled_fold_fn`); the caller
+    loops yB/W windows (``j0`` a multiple of W), donating the
     accumulator across launches.
 
-    The program is FULLY STATIC (no lax.scan: every loop-carried
-    formulation of the multi-GiB accumulator with a non-trivial body
-    lost XLA:TPU's carry aliasing — compile-time "Used 18.07G of
-    15.75G" — or hung the remote AOT compiler), and its width W is sized
-    so that ALL facets' stage planes fit HBM even fully
-    concurrently-scheduled (the compiler strips optimization_barrier and
-    overlaps every block).
+    ``tab`` is `_ct_fold_tables`' packed kt + lane table. Everything is
+    batched over the facets: per window the live planes are the lane
+    sums G [F, Q, P, W] and the stage-2 output [F, Qi, P, W] (complex),
+    which `_ct_fold_width` sizes W for. The window is one slot of the
+    accumulator seen as [F, yB, yB/W, W(,2)], laid out as the
+    accumulator is (re/im inside each row's lane tiles): the slot index
+    is a major axis, so its slice/update is one in-place fusion. Sliced
+    on its minor axis instead, the update ran at a tenth of the HBM rate
+    (262 of 630 ms a call on a v5e at 32k).
     """
     import jax.numpy as jnp
 
     yN = core.yN_size
     planar = _planar(core)
 
-    def fn(acc, rows, e0, krows, r_idx, a_vals, j0):
+    def fn(acc, rows, e0, tab, j0):
         F, yB = acc.shape[0], acc.shape[1]
-        g = r_idx.shape[0]
-        fdt = acc.dtype if planar else core._Fb.real.dtype
+        R = rows.shape[1]
+        n = tab.shape[0] - R
+        g = n // (Q * kmax)
+        rdt = acc.dtype if planar else core._Fb.real.dtype
         Qi = -(-yB // P)
-        yB_pad = Qi * P
-
-        # e0 pre-rotation: rows2 = rows * W^{-e0_f kt_r} (the sampled
-        # fold's own formula, exact int32 via _mulmod)
-        p_cos, p_sin = _sampled_phases(
-            core, _mulmod(e0.astype(jnp.int32)[:, None], krows[None, :], yN)
-        )
-        # stage-1 a-phases: T[c, b, k, p] = exp(-2pi i a p / P), zeroed
-        # on pads (a = -1)
+        z = jnp.int32(0)
+        ztail = (z,) * (acc.ndim - 3)
+        kt, r_idx = tab[:R], tab[R:]
+        r_safe = jnp.maximum(r_idx, 0)
+        # stage-1 phases W^{-kt (e0 + p)} of every gathered row, zero on
+        # the lanes' pad slots: [F, n, P]
         pj = jnp.arange(P, dtype=jnp.int32)
-        a_safe = jnp.maximum(a_vals, 0)
-        theta1 = (-2 * np.pi / P) * jnp.mod(
-            a_safe[..., None] * pj, P
-        ).astype(fdt)
-        mask = (a_vals >= 0).astype(fdt)[..., None]
-        T_re = jnp.cos(theta1) * mask
-        T_im = jnp.sin(theta1) * mask
-        # stage-2 twiddle W2[b, p] = exp(-2pi i b p / yN): b*p < Q*P =
-        # yN, int32-exact
-        bj = jnp.arange(Q, dtype=jnp.int32)
-        theta2 = (-2 * np.pi / yN) * (bj[:, None] * pj[None, :]).astype(fdt)
-        W2_re, W2_im = jnp.cos(theta2), jnp.sin(theta2)
-        # stage-3 DFT D[q, b] = exp(-2pi i q b / Q)
-        qj = jnp.arange(Qi, dtype=jnp.int32)
-        theta3 = (-2 * np.pi / Q) * jnp.mod(
-            qj[:, None] * bj[None, :], Q
-        ).astype(fdt)
-        D_re, D_im = jnp.cos(theta3), jnp.sin(theta3)
+        res = _mulmod(
+            jnp.take(kt, r_safe)[None, :, None],
+            e0.astype(jnp.int32)[:, None, None] + pj[None, None, :],
+            yN,
+        )
+        t_cos, t_sin = _sampled_phases(core, res)
+        live = (r_idx >= 0).astype(rdt)[None, :, None]
+        t_re = (t_cos * live).astype(rdt).reshape(F, g, Q, kmax, P)
+        t_im = (-t_sin * live).astype(rdt).reshape(F, g, Q, kmax, P)
+        # stage-2 DFT D[q, b] = e^{-2pi i q b / Q}
+        qb = jnp.mod(
+            jnp.arange(Qi, dtype=jnp.int32)[:, None]
+            * jnp.arange(Q, dtype=jnp.int32)[None, :],
+            Q,
+        )
+        theta = (-2 * np.pi / Q) * qb.astype(rdt)
+        d_re, d_im = jnp.cos(theta), jnp.sin(theta)
         fb = core._p.extract_mid(core._Fb, yB, 0)  # [yB] real, no 1/yN
-        fbj = jnp.asarray(fb.real if not planar else fb, fdt)
-        flat_idx = r_idx.reshape(-1)  # [g*Q*kmax] constant gather
+        fb = jnp.asarray(fb.real if not planar else fb, rdt)
 
         from ..ops.planar_backend import matmul_precision
 
         prec = matmul_precision()
-
-        def ein(spec, A, B):
-            return jnp.einsum(spec, A, B, precision=prec)
-
-        def fold_one(facet_rows, ws):
-            """One facet's j-slice: gathered rows (planes or complex)
-            [g, Q, kmax, w] -> finished [w-slice of out rows]."""
-            if planar:
-                grc, gic = facet_rows
-                G_re = ein("cbkp,cbkj->bpj", T_re, grc) - ein(
-                    "cbkp,cbkj->bpj", T_im, gic
-                )
-                G_im = ein("cbkp,cbkj->bpj", T_re, gic) + ein(
-                    "cbkp,cbkj->bpj", T_im, grc
-                )
-                G2_re = (
-                    G_re * W2_re[:, :, None] - G_im * W2_im[:, :, None]
-                )
-                G2_im = (
-                    G_im * W2_re[:, :, None] + G_re * W2_im[:, :, None]
-                )
-                O_re = ein("qb,bpj->qpj", D_re, G2_re) - ein(
-                    "qb,bpj->qpj", D_im, G2_im
-                )
-                O_im = ein("qb,bpj->qpj", D_re, G2_im) + ein(
-                    "qb,bpj->qpj", D_im, G2_re
-                )
-                out = jnp.stack(
-                    [
-                        O_re.reshape(yB_pad, ws)[:yB],
-                        O_im.reshape(yB_pad, ws)[:yB],
-                    ],
-                    axis=-1,
-                )
-                return out * fbj[:, None, None]
-            (gth,) = facet_rows
-            T = (T_re + 1j * T_im).astype(core.dtype)
-            G = jnp.einsum("cbkp,cbkj->bpj", T, gth)
-            W2 = (W2_re + 1j * W2_im).astype(core.dtype)
-            G2 = G * W2[:, :, None]
-            D = (D_re + 1j * D_im).astype(core.dtype)
-            out = jnp.einsum("qb,bpj->qpj", D, G2).reshape(yB_pad, ws)[
-                :yB
-            ]
-            return out * fbj.astype(core.dtype)[:, None]
-
-        z = jnp.int32(0)
-        ztail = (z,) * (len(acc.shape) - 3)
-        for f in range(F):
-            if planar:
-                blkf = jax.lax.dynamic_slice(
-                    rows, (jnp.int32(f), z, j0, z),
-                    (1, rows.shape[1], W, 2),
-                )[0]
-                Rr, Ri = blkf[..., 0], blkf[..., 1]
-                Rr2 = Rr * p_cos[f, :, None] + Ri * p_sin[f, :, None]
-                Ri2 = Ri * p_cos[f, :, None] - Rr * p_sin[f, :, None]
-                facet_rows = (
-                    jnp.take(Rr2, flat_idx, axis=0).reshape(
-                        (g, Q, kmax, W)
-                    ),
-                    jnp.take(Ri2, flat_idx, axis=0).reshape(
-                        (g, Q, kmax, W)
-                    ),
-                )
-            else:
-                blkf = jax.lax.dynamic_slice(
-                    rows, (jnp.int32(f), z, j0), (1, rows.shape[1], W)
-                )[0]
-                phi = (p_cos[f] - 1j * p_sin[f]).astype(core.dtype)
-                facet_rows = (
-                    jnp.take(blkf * phi[:, None], flat_idx, axis=0)
-                    .reshape((g, Q, kmax, W)),
-                )
-            out = fold_one(facet_rows, W)
-            # explicit slice/update (NOT .at[...].add, whose interior
-            # slice lowers to scatter): the DUS chain is what the
-            # compiler in-places through the donated acc
-            cur = jax.lax.dynamic_slice(
-                acc, (jnp.int32(f), z, j0) + ztail,
-                (1, yB, W) + acc.shape[3:],
-            )
-            acc = jax.lax.dynamic_update_slice(
-                acc, cur + out[None], (jnp.int32(f), z, j0) + ztail
-            )
-        return acc
+        blk = jax.lax.dynamic_slice(
+            rows, (z, z, j0) + ztail, (F, R, W) + rows.shape[3:]
+        )
+        x = jnp.take(blk, r_safe, axis=1).reshape(
+            (F, g, Q, kmax, W) + rows.shape[3:]
+        )
+        # stage 1: per lane, a multiply-add over its g*kmax rows
+        terms = [(c, k) for c in range(g) for k in range(kmax)]
+        if planar:
+            x_re, x_im = x[..., 0], x[..., 1]
+            g_re = g_im = 0
+            for c, k in terms:
+                tr = t_re[:, c, :, k, :, None]
+                ti = t_im[:, c, :, k, :, None]
+                xr = x_re[:, c, :, k, None, :]
+                xi = x_im[:, c, :, k, None, :]
+                g_re = g_re + tr * xr - ti * xi
+                g_im = g_im + tr * xi + ti * xr
+            # stage 2 as ONE real matmul over (re/im, b):
+            # [[Dr, -Di], [Di, Dr]] @ [Gr; Gi]
+            d2 = jnp.stack(
+                [
+                    jnp.concatenate([d_re, d_im], axis=0),
+                    jnp.concatenate([-d_im, d_re], axis=0),
+                ],
+                axis=1,
+            )  # [2Qi, 2, Q]
+            o = jnp.einsum(
+                "qcb,fcbpj->fqpj",
+                d2,
+                jnp.stack([g_re, g_im], axis=1),
+                precision=prec,
+            )  # [F, 2Qi, P, W]: re rows, then im rows
+            # one transpose puts re/im next to j, as the accumulator has
+            o = jnp.moveaxis(o.reshape(F, 2, Qi, P, W), 1, -1)
+        else:
+            t = (t_re + 1j * t_im).astype(core.dtype)
+            gs = 0
+            for c, k in terms:
+                gs = gs + t[:, c, :, k, :, None] * x[:, c, :, k, None, :]
+            d = (d_re + 1j * d_im).astype(core.dtype)
+            o = jnp.einsum("qb,fbpj->fqpj", d, gs, precision=prec)
+        tail = acc.shape[3:]
+        out = o.reshape((F, Qi * P, 1, W) + tail)[:, :yB] * fb.reshape(
+            (1, yB, 1, 1) + (1,) * len(tail)
+        )
+        # the window as a slot of a [F, yB, yB/W, W(,2)] view: its index
+        # is a major axis (each slot whole lane tiles), so the add lands
+        # in place in the donated accumulator's own layout
+        order = (0, 1, 2) + ((4, 3) if planar else (3,))
+        view = _layout(acc.reshape((F, yB, yB // W, W) + tail), order)
+        at = (z, z, j0 // W, z) + ztail
+        cur = jax.lax.dynamic_slice(view, at, (F, yB, 1, W) + tail)
+        view = jax.lax.dynamic_update_slice(
+            view, cur + _layout(out, order).astype(acc.dtype), at
+        )
+        return _layout(view, order).reshape(acc.shape)
 
     return fn
 
@@ -2030,14 +2024,10 @@ def _bwd_ct_fold_j(core, Q, P, kmax, W):
 def _bwd_ct_fold_sharded(core, mesh, Q, P, kmax, W):
     """Facet-sharded CT fold (all stages facet-local; no collectives)."""
     return _shmap(
-        _scoped(
-            "swiftly/bwd.ct_fold",
-            _bwd_ct_fold_fn(core, Q, P, kmax, W, axis_name=FACET_AXIS),
-        ),
+        _scoped("swiftly/bwd.ct_fold", _bwd_ct_fold_fn(core, Q, P, kmax, W)),
         mesh,
         in_specs=(
-            _P(FACET_AXIS), _P(FACET_AXIS), _P(FACET_AXIS), _P(),
-            _P(), _P(), _P(),
+            _P(FACET_AXIS), _P(FACET_AXIS), _P(FACET_AXIS), _P(), _P(),
         ),
         out_specs=_P(FACET_AXIS),
         donate=(0,),
@@ -2045,22 +2035,11 @@ def _bwd_ct_fold_sharded(core, mesh, Q, P, kmax, W):
 
 
 def resolve_fold_mode() -> str:
-    """Backward fold body: SWIFTLY_FOLD = sampled | ct | fft | auto.
-
-    "auto" -> sampled. The alternatives cut fold FLOPs substantially
-    (ct: CT-factored, ~5x fewer at fold groups of 3; fft: spectral embed
-    + matmul-FFT, ~2x) and both are exact (tests pin all three), but on
-    an earlier v5e runtime neither REALIZED the win: the AOT compiler
-    in-places the multi-GiB accumulator only through the sampled fold's
-    2-einsum scan body (every richer loop body lost carry aliasing —
-    compile "Used 18.07G of 15.75G" — or hung the compiler;
-    optimization_barrier is stripped, so unrolled programs schedule all
-    blocks concurrently, and width-limited launch chains paid a ~70 ms
-    per-dispatch floor x yB/W launches). Measured there: sampled 0.52
-    s/fold (g=2) vs fft 1.71 s (g=3, 22 launches) vs ct compile-OOM at
-    every one-launch shape. The three are still to be measured on the
-    chip tool's machine.
-    """
+    """SWIFTLY_FOLD = auto | sampled | ct | fft: the fold body of a
+    `StreamedBackward`. An explicit body is forced on every call (the
+    fft body at 32k took 4.0-4.2 s a call on a v5e, against 0.13 s for
+    ct and 0.26-0.40 s for sampled); "auto", the default, lets
+    `select_fold_body` pick per call."""
     import os
 
     mode = os.environ.get("SWIFTLY_FOLD", "auto")
@@ -2068,7 +2047,30 @@ def resolve_fold_mode() -> str:
         raise ValueError(
             f"SWIFTLY_FOLD must be ct|fft|sampled|auto, got {mode!r}"
         )
-    return "sampled" if mode == "auto" else mode
+    return mode
+
+
+def select_fold_body(mode, yN, n_rows, meshed=False, row_slab=False):
+    """The body one fold call of ``n_rows`` concatenated rows runs.
+
+    An explicit ``mode`` is kept. "auto" takes the CT-factored body where
+    the call is deep against the CT lane count Q = gcd(128, yN), n_rows
+    >= CT_MIN_LANE_DEPTH * Q, and the sampled body otherwise. A CT call
+    costs about the same whatever its depth; the dense sampled call
+    grows with it. On a v5e at 32k (9 facets of 11264, Q 128, m 256
+    rows a column) a call took, in device time: CT 130 ms at 256 rows
+    and 131 ms at 512; sampled 260 ms and 397 ms. Row slabs (the CT body
+    folds whole facets) and facet meshes (no cell runs a backward on
+    one yet) keep the sampled body.
+    """
+    import math
+
+    if mode != "auto":
+        return mode
+    if meshed or row_slab:
+        return "sampled"
+    Q = math.gcd(128, yN)
+    return "ct" if n_rows >= CT_MIN_LANE_DEPTH * Q else "sampled"
 
 
 # -- device-side sparse facet synthesis -------------------------------------
@@ -4021,14 +4023,14 @@ class StreamedBackward:
         self._naf = {}  # off0 -> host/device [F, m, yB_pad(,2)] rows
         self._acc = None  # ("sampled") device [F, yB, yB(,2)] accumulator
         self._fold_group = max(1, int(fold_group))
-        self._fold_mode = resolve_fold_mode()  # sampled | ct | fft
+        self._fold_mode = resolve_fold_mode()  # auto | sampled | ct | fft
         self._row_slab = None
         if row_slab is not None:
             r0, r1 = int(row_slab[0]), int(row_slab[1])
             yB = self._base.stack.size
             if residency != "sampled":
                 raise ValueError("row_slab requires residency='sampled'")
-            if self._fold_mode != "sampled":
+            if self._fold_mode not in ("sampled", "auto"):
                 raise ValueError(
                     "row_slab requires the sampled fold body "
                     f"(SWIFTLY_FOLD=sampled|auto, got {self._fold_mode!r})"
@@ -4039,6 +4041,7 @@ class StreamedBackward:
                 )
             self._row_slab = (r0, r1)
         self._pending_rows = []  # ("sampled") [(off0, rows [F, m, yB(,2)])]
+        self._ct_starts = {}  # CT fold launch width -> device window starts
         # ("sampled") depth-2 fold-completion pipeline: dispatch is
         # asynchronous and block_until_ready was not completion on an
         # earlier runtime (on the chip: still to be measured), so a
@@ -4247,9 +4250,13 @@ class StreamedBackward:
 
     def _fold_rows(self, offs, rows_cat):
         """("sampled") one adjoint fold of concatenated column rows
-        [F, P*m, yB(,2)] into the image-space accumulator — the direct
-        adjoint-sampled einsum by default (see `resolve_fold_mode`), the
-        CT-factored body with SWIFTLY_FOLD=ct."""
+        [F, g*m, yB(,2)] into the image-space accumulator, through the
+        body `select_fold_body` picks for the call: the CT-factored body
+        where the call is deep enough, else the direct adjoint-sampled
+        einsum (or SWIFTLY_FOLD's forced body). Either body's wall is
+        the plan's priced ``bwd.sampled_fold`` stage, at the fold's
+        modelled FLOPs; the body shows in its counter and its
+        ``swiftly/`` device scope."""
         import jax.numpy as jnp
 
         base = self._base
@@ -4261,30 +4268,44 @@ class StreamedBackward:
             e0 = self._e0_dev = base._place(
                 (np.asarray(base.stack.offs0) - yB // 2).astype(np.int32)
             )
-        krows = jnp.asarray(sampled_row_indices(core, offs))
         self._drain_folds()
-        if self._fold_mode == "ct":
-            Q, P, kmax, r_idx, a_vals = _ct_fold_tables(core, offs)
-            F = base.stack.n_total // _mesh_size(base.mesh)
-            itemsize = np.dtype(_np_dtype(core)).itemsize
-            planes = 2 * F * core.yN_size * yB * (
-                itemsize if _planar(core) else itemsize // 2
+        body = select_fold_body(
+            self._fold_mode, core.yN_size, int(rows_cat.shape[1]),
+            meshed=base.mesh is not None,
+            row_slab=self._row_slab is not None,
+        )
+        if body == "ct":
+            from ..plan import model as _plan_model
+
+            Q, P, kmax, tab = _ct_fold_tables(
+                core, tuple(int(o) for o in offs)
             )
-            W = _ct_fold_width(yB, planes)
+            F = base.stack.n_total // _mesh_size(base.mesh)
+            W = _ct_fold_width(
+                yB, _ct_column_bytes(core, F, yB),
+                _plan_model.DEFAULT_RESERVE_BYTES,
+            )
             if base.mesh is not None:
                 foldfn = _bwd_ct_fold_sharded(
                     core, base.mesh, Q, P, kmax, W
                 )
             else:
                 foldfn = _bwd_ct_fold_j(core, Q, P, kmax, W)
-            ri, av = jnp.asarray(r_idx), jnp.asarray(a_vals)
-            with _metrics.stage("bwd.ct_fold"):
-                for j0 in range(0, yB, W):
-                    self._acc = foldfn(
-                        self._acc, rows_cat, e0, krows, ri, av,
-                        jnp.int32(j0),
-                    )
+            if _metrics.enabled():
+                _metrics.count("bwd.ct_folds")
+            # the window starts go to the device once, not every launch
+            starts = self._ct_starts.get(W)
+            if starts is None:
+                starts = self._ct_starts[W] = [
+                    jnp.int32(j0) for j0 in range(0, yB, W)
+                ]
+            tab = jnp.asarray(tab)
+            with _metrics.stage("bwd.sampled_fold",
+                                flops=self._fold_flops(rows_cat)):
+                for j0 in starts:
+                    self._acc = foldfn(self._acc, rows_cat, e0, tab, j0)
         else:
+            krows = jnp.asarray(sampled_row_indices(core, offs))
             if base.mesh is not None:
                 foldfn = _bwd_sampled_fold_sharded(core, base.mesh)
             else:
@@ -4296,22 +4317,32 @@ class StreamedBackward:
                 )
                 if kernel == "pallas" and _metrics.enabled():
                     _metrics.count("bwd.pallas_folds")
-            fold_flops = 0
             if _metrics.enabled():
-                from ..utils.flops import bwd_fold_flops
-
-                fold_flops = bwd_fold_flops(
-                    core, base.stack.n_real, yB, int(rows_cat.shape[1])
-                )
-                if self._row_slab is not None:
-                    # fold FLOPs scale with the output rows computed
-                    r0, r1 = self._row_slab
-                    fold_flops = int(fold_flops * (r1 - r0) / yB)
+                _metrics.count("bwd.sampled_folds")
             row0 = jnp.int32((self._row_slab or (0, 0))[0])
-            with _metrics.stage("bwd.sampled_fold", flops=fold_flops):
+            with _metrics.stage("bwd.sampled_fold",
+                                flops=self._fold_flops(rows_cat)):
                 self._acc = foldfn(self._acc, rows_cat, e0, krows, row0)
         # the checksum slice depends on the whole fold having executed
         self._fold_inflight.append(jnp.sum(self._acc[:, 0]))
+
+    def _fold_flops(self, rows_cat):
+        """The modelled FLOPs of one fold call (`bwd_fold_flops`, the
+        same work whichever body runs it), or 0 with metrics off."""
+        if not _metrics.enabled():
+            return 0
+        from ..utils.flops import bwd_fold_flops
+
+        base = self._base
+        yB = base.stack.size
+        flops = bwd_fold_flops(
+            base.core, base.stack.n_real, yB, int(rows_cat.shape[1])
+        )
+        if self._row_slab is not None:
+            # fold FLOPs scale with the output rows computed
+            r0, r1 = self._row_slab
+            flops = int(flops * (r1 - r0) / yB)
+        return flops
 
     def _fold_rows_fft(self, offs, rows_g):
         """("sampled", fft fold) one FFT-based adjoint fold of a column
@@ -4344,7 +4375,7 @@ class StreamedBackward:
     def _flush_folds(self):
         """("sampled") fold the pending columns' rows into the image-space
         accumulator: one fold over the pending group, via the body
-        `resolve_fold_mode` selected (sampled einsum by default)."""
+        `select_fold_body` picks (or the fft body SWIFTLY_FOLD forces)."""
         import jax.numpy as jnp
 
         if not self._pending_rows:

@@ -487,12 +487,11 @@ def test_backward_path_lowers_without_unusable_donations():
     if bad:
         problems["fft_fold"] = [str(w.message) for w in bad]
 
-    Q, Pq, kmax, r_idx, a_vals = _ct_fold_tables(core, offs)
+    Q, Pq, kmax, tab = _ct_fold_tables(core, tuple(offs))
     ctfold = _bwd_ct_fold_j(core, Q, Pq, kmax, yB)
     bad = unusable_donation_warnings(
         lambda: ctfold.lower(
-            acc, rows, e0, krows, jnp.asarray(r_idx),
-            jnp.asarray(a_vals), jnp.int32(0),
+            acc, rows, e0, jnp.asarray(tab), jnp.int32(0),
         ).compile()
     )
     if bad:

@@ -395,10 +395,11 @@ def test_forward_rejects_sampled_residency():
         (3, "sampled"),
         (1, "fft"),
         (1, "ct"),
-        # the fold_group axis for the NON-default bodies is -m slow
+        # the fold_group axis for the fft and ct bodies is -m slow
         # (tier-1 brushes the driver window): batching more columns per
         # fold is the same code path at a different static shape, the
-        # default sampled body keeps both group sizes in tier-1, and
+        # sampled body keeps both group sizes in tier-1, auto runs ct
+        # on every call of 2+ columns in the other tier-1 tests, and
         # the grouped fft/ct feed paths are exercised by the
         # add_subgrid_group chunking tests
         pytest.param(3, "fft", marks=pytest.mark.slow),
@@ -427,6 +428,142 @@ def test_sampled_backward_matches_fft_backward(
     np.testing.assert_allclose(out, ref, atol=1e-10)
 
 
+def _m32k():
+    """(yN, m) of the 32k round trip's configuration."""
+    from swiftly_tpu import SWIFT_CONFIGS
+
+    c = SWIFT_CONFIGS["32k[1]-n16k-512"]
+    return c["yN_size"], c["xM_size"] * c["yN_size"] // c["N"]
+
+
+@pytest.mark.parametrize(
+    "mode,yN,n_rows,meshed,row_slab,want",
+    [
+        # the 32k round trip's calls (m 256, Q 128): g = 2 and g = 1
+        ("auto", _m32k()[0], 2 * _m32k()[1], False, False, "ct"),
+        ("auto", _m32k()[0], _m32k()[1], False, False, "ct"),
+        # below CT_MIN_LANE_DEPTH * Q rows (test shapes: m 128, Q 128)
+        ("auto", 512, 128, False, False, "sampled"),
+        ("auto", _m32k()[0], 255, False, False, "sampled"),
+        # a row slab or a facet mesh keeps the sampled body
+        ("auto", _m32k()[0], 512, False, True, "sampled"),
+        ("auto", _m32k()[0], 512, True, False, "sampled"),
+        # an explicit SWIFTLY_FOLD overrides auto
+        ("sampled", _m32k()[0], 512, False, False, "sampled"),
+        ("ct", 512, 128, False, False, "ct"),
+        ("fft", _m32k()[0], 512, False, False, "fft"),
+    ],
+)
+def test_fold_body_selection(
+    mode, yN, n_rows, meshed, row_slab, want, monkeypatch
+):
+    """`select_fold_body`: CT where the call is deep against Q, else
+    the sampled body; SWIFTLY_FOLD forces one."""
+    from swiftly_tpu.parallel.streamed import (
+        resolve_fold_mode,
+        select_fold_body,
+    )
+
+    if mode == "auto":
+        monkeypatch.delenv("SWIFTLY_FOLD", raising=False)
+    else:
+        monkeypatch.setenv("SWIFTLY_FOLD", mode)
+    got = select_fold_body(resolve_fold_mode(), yN, n_rows, meshed, row_slab)
+    assert got == want
+
+
+def test_fold_counters_count_every_call(monkeypatch):
+    """Under auto, each fold call counts once under the body it ran: at
+    the test shapes 5 columns in fold groups of 2 are two CT calls (256
+    rows) and one sampled call (128 rows); both bodies time under the
+    plan's priced fold stage, and the facets equal the all-sampled
+    backward's."""
+    from swiftly_tpu.obs import metrics
+
+    config, facet_configs, subgrid_configs, facet_tasks = _setup("planar")
+    fwd = StreamedForward(config, facet_tasks, col_block=416)
+    subgrids = fwd.all_subgrids(subgrid_configs)
+    tasks = [(sg, subgrids[i]) for i, sg in enumerate(subgrid_configs)]
+    n_cols = len({sg.off0 for sg in subgrid_configs})
+
+    def run():
+        b = StreamedBackward(
+            config, facet_configs, residency="sampled", fold_group=2
+        )
+        b.add_subgrids(tasks)
+        return b.finish()
+
+    monkeypatch.setenv("SWIFTLY_FOLD", "sampled")
+    ref = run()
+    monkeypatch.delenv("SWIFTLY_FOLD")
+    metrics.reset()
+    metrics.enable()
+    try:
+        out = run()
+        exported = metrics.export()
+    finally:
+        metrics.disable()
+        metrics.reset()
+    counters, stages = exported["counters"], exported["stages"]
+    assert counters["bwd.ct_folds"] == n_cols // 2
+    assert counters["bwd.sampled_folds"] == n_cols % 2
+    assert counters["bwd.ct_folds"] + counters["bwd.sampled_folds"] == (
+        -(-n_cols // 2)
+    )
+    assert stages["bwd.sampled_fold"]["count"] == -(-n_cols // 2)
+    assert stages["bwd.sampled_fold"]["flops"] > 0
+    np.testing.assert_allclose(out, ref, atol=1e-10)
+
+
+@pytest.mark.parametrize(
+    "yB,per_col,budget,want",
+    [
+        # 32k: 9 facets, Q 128, Qi 88, P 128, f32 planes; 1.2 GB reserve
+        (11264, 9 * (128 + 88) * 128 * 4 * 2, 1.2e9, 512),
+        (11264, 1, 1e12, 11264),  # everything fits: one launch
+        (416, 1000, 1.1e5, 104),  # no lane-aligned divisor of 416
+    ],
+)
+def test_ct_fold_width(yB, per_col, budget, want):
+    from swiftly_tpu.parallel.streamed import _ct_fold_width
+
+    assert _ct_fold_width(yB, per_col, budget) == want
+
+
+def test_ct_fold_several_launches_matches_sampled(monkeypatch):
+    """With a small reserve the CT fold runs each call as several
+    j-window launches (W < yB), and equals the sampled fold."""
+    from swiftly_tpu.plan import model
+    from swiftly_tpu.parallel import streamed as st
+
+    config, facet_configs, subgrid_configs, facet_tasks = _setup("planar")
+    fwd = StreamedForward(config, facet_tasks, col_block=416)
+    subgrids = fwd.all_subgrids(subgrid_configs)
+    tasks = [(sg, subgrids[i]) for i, sg in enumerate(subgrid_configs)]
+    widths = []
+    real_width = st._ct_fold_width
+
+    def width(*args):
+        widths.append(real_width(*args))
+        return widths[-1]
+
+    def run(mode):
+        monkeypatch.setenv("SWIFTLY_FOLD", mode)
+        b = StreamedBackward(
+            config, facet_configs, residency="sampled", fold_group=3
+        )
+        b.add_subgrids(tasks)
+        return b.finish()
+
+    ref = run("sampled")
+    monkeypatch.setattr(model, "DEFAULT_RESERVE_BYTES", 5e6)
+    monkeypatch.setattr(st, "_ct_fold_width", width)
+    out = run("ct")
+    yB = TEST_PARAMS["yB_size"]
+    assert widths and all(yB % w == 0 and w < yB for w in widths)
+    np.testing.assert_allclose(out, ref, atol=1e-10)
+
+
 def test_sampled_fold_row_blocking(monkeypatch):
     """The row-blocked adjoint fold — multiple blocks including a clamped
     final block (416 % 100 != 0) — is exactly the single-block fold.
@@ -434,6 +571,9 @@ def test_sampled_fold_row_blocking(monkeypatch):
     This is the 32k-OOM fix's correctness pin: blocking bounds the fold
     transient to [F, B, yB] instead of a second full accumulator."""
     from swiftly_tpu.parallel import streamed as st
+
+    # the sampled body's row blocking, whatever auto would pick here
+    monkeypatch.setenv("SWIFTLY_FOLD", "sampled")
 
     config, facet_configs, subgrid_configs, facet_tasks = _setup("planar")
     fwd = StreamedForward(config, facet_tasks, col_block=416)

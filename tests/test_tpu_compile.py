@@ -1,4 +1,5 @@
-"""AOT compiles of the Pallas kernels for a described TPU v5e.
+"""AOT compiles of the Pallas kernels, and of the CT fold launch, for a
+described TPU v5e.
 
 Interpret mode (tests/test_pallas.py) checks what the kernels compute;
 only Mosaic says whether they compile: it refuses slices off the tiling
@@ -167,6 +168,40 @@ def test_bwd_fold_compiles_at_streamed_shapes(one_chip, monkeypatch, name):
     monkeypatch.undo()
     args, kwargs = calls[0]
     _compile(pallas_kernels.bwd_fold_pallas, one_chip, args, **kwargs)
+
+
+@pytest.mark.parametrize("g", [1, 2])
+def test_ct_fold_compiles_in_place_at_32k(one_chip, g):
+    """One CT fold launch at the 32k round trip's calls (9 facets, g
+    columns) updates the donated 9.1 GB accumulator in place, its
+    transients inside the plan's reserve: a body that relayouts the
+    whole accumulator does not fit the chip and fails to compile."""
+    from swiftly_tpu.parallel import streamed
+    from swiftly_tpu.plan.model import DEFAULT_RESERVE_BYTES
+
+    params, core = _core("32k[1]-n16k-512")
+    yB, m, F = params["yB_size"], core.xM_yN_size, 9
+    offs = tuple(c * params["xA_size"] for c in range(g))
+    Q, P, kmax, tab = streamed._ct_fold_tables(core, offs)
+    W = streamed._ct_fold_width(
+        yB, streamed._ct_column_bytes(core, F, yB), DEFAULT_RESERVE_BYTES
+    )
+    assert W < yB and yB % W == 0 and W % 128 == 0
+    fold = jax.jit(
+        streamed._bwd_ct_fold_fn(core, Q, P, kmax, W), donate_argnums=0
+    )
+    acc = jax.ShapeDtypeStruct((F, yB, yB, 2), jnp.float32, sharding=one_chip)
+    args = [
+        acc,
+        jax.ShapeDtypeStruct((F, g * m, yB, 2), jnp.float32,
+                             sharding=one_chip),
+        jax.ShapeDtypeStruct((F,), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct(tab.shape, jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+    ]
+    mem = fold.lower(*args).compile().memory_analysis()
+    assert mem.alias_size_in_bytes == F * yB * yB * 2 * 4
+    assert mem.temp_size_in_bytes <= DEFAULT_RESERVE_BYTES
 
 
 @pytest.mark.parametrize("n", [512, 1024])
