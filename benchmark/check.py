@@ -11,7 +11,9 @@ What the window produced is held against the plain reference
   largest ``||got - ref|| / ||sky||``, where ``ref`` is the facet's
   source pixels and ``||sky||`` the norm of all of them; computed on
   the device, a block of rows at a time, in float32;
-* ``missing``: drawn subgrids that the window never produced (limit 0).
+* ``missing``: columns of the run's share (the whole cover where the
+  configuration states none) whose drawn subgrid the window never
+  produced (limit 0).
 """
 
 from __future__ import annotations
@@ -79,7 +81,7 @@ def compare(op, limits):
     for (off0, off1), got in op.samples.items():
         want = reference.subgrid(op.N, op.xA, op.sources, off0, off1)
         errs.append(reference.relative_error(got, want))
-    missing = op.expected_samples() - len(op.samples)
+    missing = len(set(op.col_offs) - {off0 for off0, _ in op.samples})
     checks = {
         "subgrid_err": {"value": max(errs) if errs else float("inf"),
                         "limit": limits["subgrid_err"]},

@@ -37,7 +37,7 @@ def main(argv=None):
     harness.configure(config)
     harness.use_cache()
     op = drive.OPERATIONS[res["traffic"]["operation"]](
-        config, res["cell"]["chips"])
+        config, res["cell"]["chips"], res["traffic"])
     tracer = drive.Tracer(None, op.devices)
     for i, seed in enumerate(int(s) for s in args.seeds.split(",")):
         t0 = time.perf_counter()

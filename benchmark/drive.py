@@ -16,10 +16,23 @@ boundary after ``Tracer.SKIP_GROUPS`` groups and closes at the first
 boundary ``Tracer.SECONDS`` later, with the device drained at both
 ends, so the span holds whole column groups and their work alone.
 
-The program gets only inputs made here from the seed: each facet is a
-dense real float32 plane on the host, as an image is, holding the
-sky's pixels from `reference`. From each column one subgrid, drawn
-from the seed, is copied out of the timed path for the comparison.
+A configuration file may state, as data:
+
+* ``"columns": {"first": i, "count": n}``: the run streams only a
+  contiguous share of the cover's columns (in the order of ``off0``),
+  the part one chip holds of a deployment whose columns are divided
+  over more chips. The ``forward`` mix streams the share over and over;
+  the ``roundtrip`` mix refuses one, since a share folds part of every
+  facet, which neither ``facet_err`` nor the reference computes.
+* ``"facet_input"``: ``"dense"`` (the default) or ``"components"``.
+
+The program gets only inputs made here from the seed, each facet
+holding the sky's pixels from `reference`: with ``"dense"`` a dense
+real float32 plane on the host, as an image is; with ``"components"``
+the program's own point-component facet (`SparseRealFacet`), the input
+of a predict from a component list, which the program keeps sparse or
+densifies by its own rule. From each column one subgrid, drawn from
+the seed, is copied out of the timed path for the comparison.
 """
 
 from __future__ import annotations
@@ -126,16 +139,35 @@ class Tracer:
         self.state = "done"
 
 
+FACET_INPUTS = ("dense", "components")
+
+
+def column_share(config, n_columns):
+    """``(first, count)`` of the configuration's ``columns`` share of a
+    cover of ``n_columns`` columns; the whole cover where it states
+    none."""
+    share = config.get("columns")
+    if share is None:
+        return 0, n_columns
+    first, count = int(share["first"]), int(share["count"])
+    if first < 0 or count < 1 or first + count > n_columns:
+        raise ValueError(
+            f"columns {share} is not a share of the cover's {n_columns} "
+            "columns")
+    return first, count
+
+
 class Operation:
     """Set-up, warm-up, window and answers of one traffic mix on one
     configuration. Subclasses give the operation."""
 
-    def __init__(self, config, n_chips):
+    def __init__(self, config, n_chips, traffic=None):
         import jax
 
         from swiftly_tpu import make_full_facet_cover, make_full_subgrid_cover
 
         self.config = config
+        self.traffic = dict(traffic or {})
         self.n_chips = int(n_chips)
         self.devices = jax.devices()[: self.n_chips]
         self.mesh = None
@@ -148,8 +180,17 @@ class Operation:
         self.yB = int(config["yB_size"])
         self.xA = int(config["xA_size"])
         self.facet_configs = make_full_facet_cover(self.pconfig)
-        self.cover = make_full_subgrid_cover(self.pconfig)
-        self.col_offs = sorted({sg.off0 for sg in self.cover})
+        self.facet_input = config.get("facet_input", "dense")
+        if self.facet_input not in FACET_INPUTS:
+            raise ValueError(f"facet_input {self.facet_input!r} is not one "
+                             f"of {FACET_INPUTS}")
+        cover = make_full_subgrid_cover(self.pconfig)
+        offs = sorted({sg.off0 for sg in cover})
+        first, count = column_share(config, len(offs))
+        self.columns = {"first": first, "count": count, "of": len(offs)}
+        self.col_offs = offs[first:first + count]
+        share = set(self.col_offs)
+        self.cover = [sg for sg in cover if sg.off0 in share]
         self.per_column = len(self.cover) // len(self.col_offs)
         self._take = jax.jit(lambda g, ci, ri: g[ci, ri])
         self.samples = {}
@@ -165,12 +206,18 @@ class Operation:
         self.pixels = reference.facet_pixels(self.N, self.yB, self.sources)
         if max(len(v[0]) for v in self.pixels.values()) > 1:
             raise ValueError("the sky put two pixels into one facet")
+        from swiftly_tpu.ops.oracle import SparseRealFacet
+
         self.facet_tasks = []
         for fc in self.facet_configs:
             rows, cols, vals = self.pixels[(fc.off0, fc.off1)]
-            plane = np.zeros((self.yB, self.yB), np.float32)
-            plane[rows, cols] = vals
-            self.facet_tasks.append((fc, plane))
+            if self.facet_input == "components":
+                facet = SparseRealFacet(self.yB, rows, cols,
+                                        vals.astype(np.float32))
+            else:
+                facet = np.zeros((self.yB, self.yB), np.float32)
+                facet[rows, cols] = vals
+            self.facet_tasks.append((fc, facet))
         # one subgrid of each column, drawn from the seed
         rng = np.random.default_rng([self.seed, 1])
         self.pick = {off0: int(rng.integers(self.per_column))
@@ -220,12 +267,19 @@ class Operation:
             sizes.add(n % G)
         return sorted(sizes)
 
-    def expected_samples(self):
-        return len(self.col_offs)
-
 
 class RoundTrip(Operation):
-    """Facets -> subgrids -> facets, whole passes."""
+    """Facets -> subgrids -> facets, whole passes: at least the mix's
+    ``min_passes`` (1 where it states none) a window."""
+
+    def __init__(self, config, n_chips, traffic=None):
+        super().__init__(config, n_chips, traffic)
+        if self.columns["count"] != self.columns["of"]:
+            raise ValueError(
+                "the roundtrip mix runs the whole cover: a column share "
+                "folds part of every facet, which neither facet_err nor "
+                "the reference computes")
+        self.min_passes = int(self.traffic.get("min_passes", 1))
 
     def build(self):
         from swiftly_tpu.parallel import StreamedForward
@@ -296,18 +350,20 @@ class RoundTrip(Operation):
         return facets
 
     def window(self, seconds, tracer):
-        """Passes back to back; a pass starts only where the one before
-        says it can finish inside ``seconds``, and the first always
-        runs. Returns (subgrids, seconds) of the passes counted."""
+        """Passes back to back; a pass starts where the one before says
+        it can finish inside ``seconds``, or where fewer than
+        ``min_passes`` have run. Returns (subgrids, seconds) of the
+        passes counted."""
         t0 = time.perf_counter()
-        done, t_last = 0, t0
+        done, t_last, n = 0, t0, 0
         while True:
             self.facets = None  # frees the last pass's facets
             self.facets = self.one_pass(tracer)
             t = time.perf_counter()
             done += len(self.cover)
+            n += 1
             last, t_last = t - t_last, t
-            if t - t0 + last > seconds:
+            if n >= self.min_passes and t - t0 + last > seconds:
                 break
         tracer.stop()
         self._collect()

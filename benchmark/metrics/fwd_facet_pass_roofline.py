@@ -1,0 +1,5 @@
+"""`facet_pass_roofline` in the forward cells, which report `fwd_subgrid_rate`:
+the same reading, under a name of its own because a per-layer metric
+names the one end-to-end metric it moves."""
+
+from benchmark.metrics.facet_pass_roofline import read  # noqa: F401

@@ -46,7 +46,8 @@ def high_precision(monkeypatch):
         getattr(getattr(streamed, name), "cache_clear", lambda: None)()
 
 
-@pytest.mark.parametrize("workload", ["roundtrip-32k", "forward-64k-mesh4"])
+@pytest.mark.parametrize("workload", ["roundtrip-32k", "forward-32k",
+                                      "forward-64k-mesh4"])
 def test_control_fails_the_limits(workload, high_precision):
     res = bm_helpers.tiny_cell(workload)
     res["config"]["precision"] = "high"

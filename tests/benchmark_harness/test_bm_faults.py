@@ -105,6 +105,9 @@ CASES = [
     ("roundtrip-32k", _state_unchanged),
     ("roundtrip-32k", _half_batch_to_backward),
     ("roundtrip-32k", _answer_altered),
+    ("forward-32k", _answer_altered),
+    ("forward-32k", _half_batch_forward),
+    ("forward-32k", _stale_groups),
     ("forward-64k-mesh4", _answer_altered),
     ("forward-64k-mesh4", _half_batch_forward),
     ("forward-64k-mesh4", _stale_groups),

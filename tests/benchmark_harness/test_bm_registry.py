@@ -119,6 +119,15 @@ def test_metrics():
         assert any(w in m.get("workloads", CELLS) for m in SPEC["per_layer"])
 
 
+def test_each_per_layer_metric_moves_a_metric_its_cells_report():
+    e2e = {m["name"]: set(m.get("workloads", CELLS))
+           for m in SPEC["end_to_end"]}
+    for m in SPEC["per_layer"]:
+        assert set(m.get("workloads", CELLS)) <= e2e[m["moves"]], m["name"]
+    for w in CELLS:
+        assert sum(w in cells for cells in e2e.values()) >= 2
+
+
 def test_layers_are_named_alike():
     layers = {m["layer"] for m in SPEC["per_layer"]}
     assert all(1 <= len(x) <= 200 and "\n" not in x for x in layers)

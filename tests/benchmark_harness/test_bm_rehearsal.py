@@ -52,7 +52,7 @@ def test_forward_cell_on_four_devices():
     assert r["correct"], r["checks"]
     assert r["device"]["count"] == 4
     assert set(r["checks"]) == {"subgrid_err", "missing"}
-    _well_formed(r, {"subgrid_rate", "setup_s"})
+    _well_formed(r, {"fwd_subgrid_rate", "setup_s"})
 
 
 def test_traced_run_reports_per_layer_metrics_only(monkeypatch):
