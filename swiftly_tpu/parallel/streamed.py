@@ -2198,7 +2198,11 @@ def _fused_sparse_slab_step_j(core, subgrid_size, chunk, Fg, yB, colpass):
     lax.scan program per column group — was measured 3x SLOWER at 64k
     (188.6 s vs 61.7 s full cover): the nested while-loops (slab scan >
     chunk scan > S-block map) serialize XLA's scheduling, so one
-    dispatch per slab with the depth-2 checksum pipeline stands."""
+    dispatch per slab with the depth-2 checksum pipeline stands.
+
+    The synthesis and the sampled pass each carry their own scope inside
+    the program's ``swiftly/fwd.slab_step``, which is left holding the
+    column step alone, so a device trace credits each stage apart."""
     import jax.numpy as jnp
 
     sam = _facet_pass_sampled_fn(core, real_facets=True)
@@ -2206,8 +2210,17 @@ def _fused_sparse_slab_step_j(core, subgrid_size, chunk, Fg, yB, colpass):
     dt = _np_dtype(core)
 
     def fn(acc, f, r, c, v, e0, krows, foffs0, foffs1, so_c):
-        slab = jnp.zeros((Fg, yB, yB), dtype=dt).at[f, r, c].add(v)
-        buf = sam(slab, e0, krows)
+        with jax.named_scope("swiftly/fwd.facet_synth"):
+            # the barrier keeps the zero fill the instruction this scope
+            # names: without it XLA rebuilds a one-facet slab's fill
+            # with no scope (it sinks the slab's reshape into a new
+            # broadcast); the same fill and in-place update run
+            zeros = jax.lax.optimization_barrier(
+                jnp.zeros((Fg, yB, yB), dtype=dt)
+            )
+            slab = zeros.at[f, r, c].add(v)
+        with jax.named_scope("swiftly/fwd.sampled_facet_pass"):
+            buf = sam(slab, e0, krows)
         return step(acc, buf, foffs0, foffs1, so_c)
 
     return _jit(donate=(0,))(_scoped("swiftly/fwd.slab_step", fn))
@@ -3281,6 +3294,11 @@ class StreamedForward:
         depth = 2
         if budget is not None and 2 * slab_bytes > 0.5 * budget:
             depth = 1
+        # a synthesised slab is scratch of the program that makes it,
+        # never a buffer between dispatches, so the fused step keeps one
+        # slab queued whatever the slab's size: the host's dispatch of
+        # slab d then hides behind slab d-1 on the device
+        in_flight = 2 if self._facets_sparse else depth
         chunk = 4
         if self.col_group:
             # honour an explicit G exactly: pick the largest chunk that
@@ -3516,7 +3534,7 @@ class StreamedForward:
                 )
                 slab_dev = None
                 for s0 in range(0, F_pad, Fg):
-                    while len(pending) >= depth:
+                    while len(pending) >= in_flight:
                         with _metrics.stage("fwd.drain"):
                             np.asarray(pending.popleft())
                     # drop the previous slab BEFORE uploading the next: at
@@ -3542,6 +3560,15 @@ class StreamedForward:
                                 jnp.asarray(offs0[s0 : s0 + Fg]),
                                 jnp.asarray(offs1[s0 : s0 + Fg]),
                                 so_c,
+                            )
+                        if _metrics.enabled():
+                            _metrics.count("fwd.synth_slabs")
+                            _metrics.count(
+                                "fwd.synth_pixels",
+                                sum(
+                                    d.n_pixels
+                                    for d in self._facet_data[s0 : s0 + Fg]
+                                ),
                             )
                     else:
                         d = n_slab_dispatch

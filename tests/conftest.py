@@ -50,3 +50,42 @@ def unusable_donation_warnings(fn, *args, **kwargs):
         w for w in caught
         if "donated buffers were not usable" in str(w.message).lower()
     ]
+
+
+def compiled_stages(compiled):
+    """``(stages, entry)`` of a compiled JAX program: the device scope
+    the benchmark's trace reduction credits each instruction to
+    (`benchmark.trace.instruction_stages` of the program's HLO), and
+    ``{name: (shape, opcode, line)}`` of the instructions of its entry
+    computation, read from its text (``shape`` is the dimensions, ''
+    for a tuple)."""
+    from benchmark import trace
+
+    module = (compiled.runtime_executable().hlo_modules()[0]
+              .as_serialized_hlo_module_proto())
+    size, n = bytearray(), len(module)
+    while True:  # the module as field 1 of an xla.HloProto
+        size.append((n & 0x7F) | (0x80 if n > 0x7F else 0))
+        n >>= 7
+        if not n:
+            break
+    stages = trace.instruction_stages(b"\x0a" + bytes(size) + module)
+    text = compiled.as_text()
+    entry = {}
+    for line in text[text.index("ENTRY"):].splitlines()[1:]:
+        if " = " not in line:
+            continue
+        name, rest = line.split(" = ", 1)
+        name = name.replace("ROOT", "").strip().lstrip("%")
+        if rest.startswith("("):  # a tuple: skip to its closing paren
+            depth = 0
+            for i, ch in enumerate(rest):
+                depth += {"(": 1, ")": -1}.get(ch, 0)
+                if depth == 0:
+                    break
+            shape, rest = "", rest[i + 1:].lstrip()
+        else:
+            head, rest = rest.split(" ", 1)
+            shape = head[head.find("[") + 1:head.find("]")]
+        entry[name] = (shape, rest.split("(", 1)[0], line)
+    return stages, entry

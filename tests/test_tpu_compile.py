@@ -226,3 +226,35 @@ def test_degrid_kernel_compiles(one_chip, bucket):
         (_sds((1024, 1024)), _sds((1024, 1024)), _sds((bucket,), i32),
          _sds((bucket,), i32), _sds((bucket, W)), _sds((bucket, W))),
     )
+
+
+def test_fused_slab_step_keeps_each_stage_scope_for_the_chip(one_chip):
+    """The facet-slab plan's fused step for point-component facets, in
+    slabs of one facet as the 128k plan runs it: compiled for the chip,
+    the zero fill of the synthesised plane stays under
+    ``swiftly/fwd.facet_synth`` (the chip's compiler rebuilds an
+    unguarded fill with no scope) and the column-pass kernel under
+    ``swiftly/fwd.slab_step``."""
+    from conftest import compiled_stages
+    from swiftly_tpu.parallel import streamed
+
+    params, core = _core("1k[1]-n512-512")
+    yB, xA, xM = params["yB_size"], params["xA_size"], params["xM_size"]
+    G, S, m = 2, -(-params["N"] // xA), core.xM_yN_size
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    i32 = jnp.int32
+    args = [sds((1, G, S, xM, xM, 2)), sds((1,), i32), sds((1,), i32),
+            sds((1,), i32), sds((1,)), sds((1,), i32), sds((G * m,), i32),
+            sds((1,), i32), sds((1,), i32), sds((1, G, S, 2), i32)]
+    stages, entry = compiled_stages(streamed._fused_sparse_slab_step_j(
+        core, xA, G, 1, yB, "pallas").lower(*args).compile())
+    fill = [k for k, (shape, op, _) in entry.items()
+            if op == "broadcast" and shape in (f"1,{yB},{yB}", f"{yB},{yB}")]
+    kernel = [k for k, (_, op, line) in entry.items()
+              if op == "custom-call" and "colpass_pallas" in line]
+    assert fill and kernel
+    assert {stages[k] for k in fill} == {"swiftly/fwd.facet_synth"}
+    assert {stages[k] for k in kernel} == {"swiftly/fwd.slab_step"}
